@@ -5,10 +5,9 @@ the result under benchmarks/artifacts/perf/<cell>__<tag>.json.
         [--overrides '{"attn_logits_dtype": "bfloat16"}'] [--grad-accum N] \
         [--multi-pod]
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import argparse
 import json
+import os
 
 PERF_DIR = os.path.join(os.path.dirname(__file__), "artifacts", "perf")
 
@@ -26,7 +25,8 @@ def main() -> None:
     ap.add_argument("--dp", action="store_true", help="pure data parallelism")
     args = ap.parse_args()
 
-    from repro.launch.dryrun import run_cell
+    from repro.launch.dryrun import force_host_devices, run_cell
+    force_host_devices()
     art = run_cell(args.arch, args.shape, multi_pod=args.multi_pod,
                    grad_accum=args.grad_accum, loss_chunk=args.loss_chunk,
                    overrides=json.loads(args.overrides) if args.overrides else None,

@@ -23,6 +23,9 @@ def main() -> int:
     only = args.only.split(",") if args.only else MODULES
 
     import importlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod in MODULES:

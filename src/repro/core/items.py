@@ -103,21 +103,18 @@ def take_rows(columns: Columns, idx: np.ndarray) -> Columns:
 
 # ------------------------------------------------------------------ device I/O
 def as_device_array(arr: np.ndarray) -> Any:
-    """Map a host array into a JAX device array for a kernel-backed stage,
-    without a copy where the backend allows (ISSUE 7).
-
-    The shm item codec lands contiguous buffers, so on the CPU backend the
-    DLPack import aliases the segment directly — decoded batch -> device
-    array with zero copies.  Read-only views (``np.frombuffer`` of a bytes
-    payload) and accelerator backends fall back to a ``device_put`` copy.
-    JAX itself is imported lazily: the scalar tier never pays for it.
+    """Copy a host array onto the default JAX device for a kernel-backed
+    stage, and check that it landed there: an array left on another device
+    would make the kernel compile for that device instead.  JAX itself is
+    imported lazily: the scalar tier never pays for it.
     """
     import jax
-    a = np.ascontiguousarray(arr)
-    try:
-        return jax.dlpack.from_dlpack(a)
-    except Exception:
-        return jax.device_put(a)
+    device = jax.devices()[0]
+    out = jax.device_put(np.ascontiguousarray(arr), device)
+    if out.devices() != {device}:
+        raise RuntimeError(f"array placed on {out.devices()}, not on the "
+                           f"default device {device}")
+    return out
 
 
 def as_device_columns(columns: Columns) -> Dict[str, Any]:
@@ -658,8 +655,8 @@ class ColumnarBatch:
         return out
 
     def device_columns(self) -> Dict[str, Any]:
-        """Device arrays straight from the (possibly shm-backed) column
-        buffers — :func:`as_device_array` DLPack-imports each field view."""
+        """Device arrays of the (possibly shm-backed) column buffers —
+        :func:`as_device_array` places each field view."""
         return as_device_columns(self.columns())
 
     def label_col(self, op: str) -> Optional[np.ndarray]:

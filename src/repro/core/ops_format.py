@@ -353,11 +353,18 @@ class PackOp(IngestOp):
             lens.append(n)
             flat_parts.extend(row)
             off += n
-        flat = np.concatenate(flat_parts).astype(np.int32, copy=False)
+        # pad stream and row table to bucketed sizes (empty padding rows):
+        # the kernel then compiles for a few shapes, not one per batch
+        from ..kernels.ops import bucket
+        flat = np.zeros(bucket(off), np.int32)
+        np.concatenate(flat_parts, out=flat[:off])
+        R = bucket(len(all_rows))
+        table = np.zeros((2, R), np.int32)
+        table[0, :len(starts)] = starts
+        table[1, :len(lens)] = lens
         t0 = time.perf_counter()
-        toks, mask, _ = self._pack_kernel(
-            flat, np.asarray(starts, np.int32), np.asarray(lens, np.int32),
-            S, pad_id=self.pad_id)
+        toks, mask, _ = self._pack_kernel(flat, table[0], table[1], S,
+                                          pad_id=self.pad_id)
         toks, mask = np.asarray(toks), np.asarray(mask)
         self.kernel_ms_total += (time.perf_counter() - t0) * 1000.0
         out_rows: List[Dict[str, np.ndarray]] = []
@@ -385,24 +392,15 @@ class PackOp(IngestOp):
         output (and ``_block_idx`` order) is byte-identical to the serial
         iterator — unlike scalar parallel mode, where threads race on the
         block counter.  With ``use_pallas`` the whole batch routes through
-        the ``pack_tokens`` kernel instead (ISSUE 10), falling back to the
-        scalar packer on any kernel-side failure."""
+        the ``pack_tokens`` kernel instead; a kernel failure raises."""
         items = list(items)
         if self._pack_kernel is not None and items:
-            try:
-                packed = self._kernel_pack(items)
-            except Exception:
-                packed = None   # scalar oracle fallback
-            if packed is not None:
-                out: List[IngestItem] = []
-                for item, rows in zip(items, packed):
-                    out.extend(self._emit_blocks(item, rows))
-                return out
-        if self.mode is OpMode.PARALLEL and len(items) > 1:
+            packed = self._kernel_pack(items)
+        elif self.mode is OpMode.PARALLEL and len(items) > 1:
             packed = list(self._ensure_pool().map(self._pack_rows, items))
         else:
             packed = [self._pack_rows(it) for it in items]
-        out = []
+        out: List[IngestItem] = []
         for item, rows in zip(items, packed):
             out.extend(self._emit_blocks(item, rows))
         return out

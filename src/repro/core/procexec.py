@@ -102,8 +102,11 @@ _ship_lock = threading.Lock()   # serializes the param swap on shared plans
 def _mp_context():
     """fork by default (fast spawn, inherited imports); override with
     REPRO_MP_START_METHOD=spawn|forkserver on platforms or runtimes where
-    forking a threaded parent is unsafe.  Workers only run ingestion
-    operators — never JAX/XLA — so fork-after-jax-import is benign here."""
+    forking a threaded parent is unsafe.  Workers run only host-side
+    ingestion operators: ``serialize_plans_for_worker`` refuses any op that
+    holds a device kernel, because the chip belongs to one process (the
+    coordinator, which may already hold it) and a forked child must not
+    touch JAX."""
     methods = mp.get_all_start_methods()
     want = os.environ.get("REPRO_MP_START_METHOD",
                           "fork" if "fork" in methods else "spawn")
@@ -114,7 +117,17 @@ def _mp_context():
 
 def serialize_plans_for_worker(stage_plans: Sequence[StagePlan],
                                store: DataStore) -> bytes:
-    """Pickle a stage DAG with DataStore params tokenized for the worker."""
+    """Pickle a stage DAG with DataStore params tokenized for the worker.
+
+    Refuses ops that hold a device kernel (``use_pallas``): kernel ops run
+    in the process that owns the chip, i.e. on the thread backend."""
+    for sp in stage_plans:
+        for op in sp.ops:
+            if op.params.get("use_pallas"):
+                raise ValueError(
+                    f"stage {sp.name!r}: op {op.name!r} holds a device kernel "
+                    f"(use_pallas=True); kernel ops run in the process that "
+                    f"owns the chip — use the thread backend")
     with _ship_lock:
         swapped = []
         for sp in stage_plans:

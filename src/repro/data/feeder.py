@@ -28,13 +28,21 @@ from .generators import as_file_items
 def build_lm_plan(data_store: DataStore, *, seq_len: int, rows_per_block: int,
                   pad_id: int = 0, replicas: int = 1,
                   length_partitions: Optional[Sequence[int]] = None,
+                  use_pallas: bool = False,
+                  erasure: Optional[Dict[str, Any]] = None,
                   name: str = "lm_corpus") -> IngestPlan:
-    """The canonical LM ingestion plan (DESIGN.md §2 table)."""
+    """The canonical LM ingestion plan (DESIGN.md §2 table).
+
+    ``use_pallas`` packs rows with the ``pack_tokens`` kernel; ``erasure``
+    (the ``ErasureOp`` arguments, e.g. ``{"k": 10, "m": 3}``) stores the
+    packed blocks Reed-Solomon coded."""
     plan = IngestPlan(name)
     s1 = select(plan, replicate=replicas if replicas > 1 else None)
     fmt_kw: Dict[str, Any] = {
-        "pack": {"seq_len": seq_len, "rows_per_block": rows_per_block, "pad_id": pad_id},
+        "pack": {"seq_len": seq_len, "rows_per_block": rows_per_block,
+                 "pad_id": pad_id, "use_pallas": use_pallas},
         "serialize": "packed",
+        "erasure": erasure,
     }
     if length_partitions is not None:
         fmt_kw["partition"] = {"key": "length", "scheme": "length",

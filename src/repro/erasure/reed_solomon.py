@@ -83,7 +83,10 @@ class ReedSolomon:
         total = offs[-1]
         t0 = time.perf_counter()
         if self._pallas_matmul is not None:
-            data = np.zeros((self.k, total), dtype=np.uint8)
+            # bucketed width: the kernel compiles for a few shapes, not one
+            # per batch; the zero columns encode to parity nobody reads
+            from ..kernels.ops import bucket
+            data = np.zeros((self.k, bucket(total)), dtype=np.uint8)
             for si, ps in enumerate(stripes):
                 o = offs[si]
                 for j, p in enumerate(ps):

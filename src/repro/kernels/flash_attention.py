@@ -30,10 +30,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, scale: float,
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
-        # scalar leading index must be a (start, size) slice: raw Python ints
-        # have no .shape and crash pl.load's NDIndexer on newer jax
-        k = pl.load(k_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(0, 1), pl.ds(j * bk, bk), slice(None)))[0].astype(jnp.float32)
+        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                      # (bq, bk) on the MXU
         if causal:
             qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CI;
-on a TPU backend the real kernels run.  The dry-run/roofline path stays pure
-XLA (Pallas custom-calls report no FLOPs to cost_analysis — DESIGN.md §6);
-kernels are opt-in at run time.
+``interpret`` defaults to the backend: the real kernels on a TPU, the Pallas
+interpreter on the CPU (tests).  Any other backend is an error — a run that
+lost its chip must not pass in interpret mode.  The dry-run/roofline path
+stays pure XLA (Pallas custom-calls report no FLOPs to cost_analysis —
+DESIGN.md §6); kernels are opt-in at run time.
 """
 from __future__ import annotations
 
@@ -16,8 +17,23 @@ from .gf256_matmul import gf256_matmul as _gf256
 from .pack_tokens import pack_tokens as _pack
 
 
+def bucket(n: int, minimum: int = 128) -> int:
+    """Round ``n`` up to one of four sizes per octave (at most 25% padding).
+    Host callers pad a kernel's varying dimension to it, so a stream of
+    batches of every size compiles a handful of shapes, not one per batch."""
+    n = max(n, minimum)
+    step = 1 << max(0, n.bit_length() - 3)
+    return -(-n // step) * step
+
+
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run on a TPU, or interpreted on the "
+                       f"CPU; the default backend is {backend!r}")
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import: jax locks the device count on first init.
 """Multi-pod AOT dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this builds the exact step the production job would run
@@ -22,11 +19,27 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
 import traceback
 from typing import Any, Dict, Optional
+
+#: the chip the dry-run pods are made of (a key of ``mesh.CHIP_PEAKS``)
+TARGET_KIND = "TPU v5 lite"
+HOST_DEVICES = 512
+
+
+def force_host_devices(n: int = HOST_DEVICES) -> None:
+    """Ask XLA's CPU backend for ``n`` devices to lay the pod meshes on.
+    Appends to ``XLA_FLAGS`` rather than replacing it, and must run before
+    JAX initializes a backend (it fixes the device count then)."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if flag not in flags.split():
+        os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
+
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "artifacts", "dryrun")
@@ -272,8 +285,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
              overrides: Optional[Dict[str, Any]] = None,
              sp: bool = False, dp: bool = False) -> Dict[str, Any]:
     from ..configs import SHAPES, get_config, shape_applicable
-    from .mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                       make_production_mesh)
+    from .mesh import chip_peaks, make_production_mesh
 
     cfg = get_config(arch)
     if overrides:
@@ -325,9 +337,10 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
     flops_dev = cost_full["flops"]
     bytes_dev = cost_full["bytes"]
     # roofline terms (seconds, per device = per step for SPMD)
-    compute_s = flops_dev / PEAK_FLOPS_BF16
-    memory_s = bytes_dev / HBM_BW
-    collective_s = coll["total_bytes"] / ICI_BW
+    peaks = chip_peaks(TARGET_KIND)
+    compute_s = flops_dev / peaks.flops_bf16
+    memory_s = bytes_dev / peaks.hbm_bw
+    collective_s = coll["total_bytes"] / peaks.ici_bw
 
     # useful-FLOPs model (6·N_active·tokens for train, 2·N_active·tokens fwd)
     n_active = cfg.active_param_count()
@@ -384,6 +397,7 @@ def main() -> int:
     ap.add_argument("--overrides", type=str, default=None,
                     help="JSON dict of ModelConfig overrides (perf experiments)")
     args = ap.parse_args()
+    force_host_devices()
 
     from ..configs import all_cells
     cells = (all_cells() if args.all else [(args.arch, args.shape)])

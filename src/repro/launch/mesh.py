@@ -16,24 +16,52 @@ shards 16 ways — it silently stays replicated, by design).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..models.config import ModelConfig
 
-# v5e hardware constants for the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW = 50e9                 # bytes/s per link
+
+class ChipPeaks(NamedTuple):
+    """Published per-chip peaks for the roofline."""
+    flops_bf16: float     # FLOP/s
+    hbm_bw: float         # bytes/s
+    ici_bw: float         # bytes/s per link
+
+
+#: keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+#: "TPU v5e" (197 TFLOP/s bf16, HBM at 819 GB/s, 1,600 Gbit/s of
+#: interconnect over 4 links).
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of one chip of ``device_kind``; an unknown kind is an error."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIP_PEAKS)}") from None
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """A mesh whose axes GSPMD partitions automatically (``jax.make_mesh``
+    otherwise makes them explicit, which the sharding rules here are not
+    written for)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
@@ -109,19 +137,20 @@ def make_constrain(mesh: Mesh, cfg: ModelConfig, global_batch: int,
     weight."""
     sizes = mesh_axis_sizes(mesh)
     model_n = sizes.get("model", 1)
+    # a mesh without a model axis (pure data parallelism) shards nothing on it
+    tp_ok = "model" in sizes and not dp
     bax = batch_axes(mesh, global_batch, include_model=dp)
-    vocab_ax = ("model" if (cfg.vocab_size % model_n == 0 and not dp)
-                else None)
+    vocab_ax = "model" if (tp_ok and cfg.vocab_size % model_n == 0) else None
     # sequence parallelism (long-prefill): residual stream sharded over the
     # model axis on the SEQ dim; per-layer weights are gathered instead of
     # activations all-reduced — 32k-token activations dwarf the weights.
-    sp = (not dp) and seq_shard and seq_len > 0 and seq_len % model_n == 0
+    sp = tp_ok and seq_shard and seq_len > 0 and seq_len % model_n == 0
     seq_ax = "model" if sp else None
 
     def tp(dim: int):  # model axis only if the dim divides (and not used by SP)
-        return "model" if (not sp and not dp and dim % model_n == 0) else None
+        return "model" if (tp_ok and not sp and dim % model_n == 0) else None
 
-    ep_ax = ("model" if (cfg.moe is not None and not dp
+    ep_ax = ("model" if (tp_ok and cfg.moe is not None
                          and cfg.moe.num_experts % model_n == 0) else None)
     weight_specs = {
         "w_q": P(None, tp(cfg.n_heads), None),

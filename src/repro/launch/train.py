@@ -17,23 +17,24 @@ Usage (CPU example — also examples/train_smollm.py):
   python -m repro.launch.train --arch smollm-135m --smoke --steps 200
 """
 import argparse
-import json
-import os
 import time
+from dataclasses import dataclass
+from typing import Any, Callable, Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+
+BATCH_FIELDS = ("tokens", "labels", "segments", "positions")
 
 
 def build_mesh(spec: str):
-    from .mesh import make_production_mesh
+    from .mesh import make_mesh, make_production_mesh
     if spec == "production":
         return make_production_mesh()
     if spec == "multipod":
         return make_production_mesh(multi_pod=True)
     shape = tuple(int(x) for x in spec.split("x"))
-    return jax.make_mesh(shape, ("data", "model")[:len(shape)])
+    return make_mesh(shape, ("data", "model")[:len(shape)])
 
 
 def make_batch(raw, seq_len: int, pad_id: int = 0):
@@ -50,6 +51,71 @@ def make_batch(raw, seq_len: int, pad_id: int = 0):
         & (mask > 0), labels, -1)
     return {"tokens": toks, "labels": labels, "segments": seg,
             "positions": pos}
+
+
+@dataclass
+class Trainer:
+    """The production train step for one mesh, with its shardings:
+    parameters and optimizer state by the sharding rules, the batch split
+    over the data axis."""
+    step: Callable            # jitted (params, opt_state, batch) -> (..., metrics)
+    init_opt: Callable
+    pdefs: Any
+    odefs: Any
+    params_sharding: Any
+    opt_sharding: Any
+    batch_sharding: Dict[str, Any]
+
+    def init_state(self, seed: int = 0):
+        """Random parameters from ``seed`` and fresh optimizer state, both
+        placed by their shardings."""
+        from ..models.params import init_params
+        params = init_params(jax.random.PRNGKey(seed), self.pdefs)
+        params = jax.tree.map(jax.device_put, params, self.params_sharding)
+        opt_state = jax.jit(self.init_opt,
+                            out_shardings=self.opt_sharding)(params)
+        return params, opt_state
+
+    def put_batch(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
+        """A host batch -> device arrays laid out by ``batch_sharding``."""
+        return {k: jax.device_put(batch[k], self.batch_sharding[k])
+                for k in BATCH_FIELDS}
+
+
+def make_trainer(cfg, mesh, *, global_batch: int, seq_len: int, lr: float,
+                 loss_chunk: int = 1024, grad_accum: int = 1) -> Trainer:
+    """jit the train step for ``mesh`` with production shardings."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from ..models.model import model_defs
+    from ..models.params import param_specs
+    from ..training.optim import make_optimizer, opt_state_defs
+    from ..training.steps import make_train_step
+    from .mesh import (input_shardings, make_constrain, mesh_axis_sizes,
+                       sharding_rules)
+
+    rules = sharding_rules(cfg, mesh, global_batch=global_batch)
+    sizes = mesh_axis_sizes(mesh)
+    named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
+                                   is_leaf=lambda x: isinstance(x, P))
+    pdefs = model_defs(cfg)
+    pshard = named(param_specs(pdefs, rules, sizes))
+    odefs = opt_state_defs(cfg.optimizer, pdefs)
+    oshard = named(param_specs(odefs, rules, sizes))
+    spec = jax.ShapeDtypeStruct((global_batch, seq_len), np.int32)
+    bshard = input_shardings(mesh, {k: spec for k in BATCH_FIELDS})
+
+    step_fn = make_train_step(
+        cfg, loss_chunk=min(loss_chunk, seq_len), grad_accum=grad_accum,
+        optimizer_kw={"lr": lr},
+        constrain=make_constrain(mesh, cfg, global_batch),
+        grad_shardings=pshard)
+    jitted = jax.jit(step_fn, in_shardings=(pshard, oshard, bshard),
+                     out_shardings=(pshard, oshard, None),
+                     donate_argnums=(0, 1))
+    init_opt, _, _ = make_optimizer(cfg.optimizer, lr=lr)
+    return Trainer(jitted, init_opt, pdefs, odefs, pshard, oshard, bshard)
 
 
 def main() -> int:
@@ -72,21 +138,15 @@ def main() -> int:
     ap.add_argument("--grad-accum", type=int, default=1)
     args = ap.parse_args()
 
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
     from ..configs import get_config, get_smoke
     from ..core import DataStore
     from ..data.feeder import BlockFeeder, ingest_corpus
     from ..data.generators import gen_token_documents
-    from ..models.model import model_defs
-    from ..models.params import abstract_params, init_params, param_specs
-    from ..training.checkpoint import CheckpointManager, place_on_mesh
-    from ..training.optim import make_optimizer, opt_state_defs
-    from ..training.steps import make_train_step
-    from .mesh import (input_shardings, make_constrain, mesh_axis_sizes,
-                       sharding_rules)
+    from ..models.params import abstract_params
+    from ..training.checkpoint import CheckpointManager
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     mesh = build_mesh(args.mesh)
     print(f"[train] arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
@@ -106,51 +166,33 @@ def main() -> int:
     print(f"[feed] {len(feeder)} packed blocks available")
 
     # ------------------------------------------------------ 3. jit the step
-    rules = sharding_rules(cfg, mesh, global_batch=args.batch)
-    sizes = mesh_axis_sizes(mesh)
-    named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
-                                   is_leaf=lambda x: isinstance(x, P))
-    pdefs = model_defs(cfg)
-    pshard = named(param_specs(pdefs, rules, sizes))
-    odefs = opt_state_defs(cfg.optimizer, pdefs)
-    oshard = named(param_specs(odefs, rules, sizes))
-
-    step_fn = make_train_step(
-        cfg, loss_chunk=min(1024, args.seq_len), grad_accum=args.grad_accum,
-        optimizer_kw={"lr": args.lr},
-        constrain=make_constrain(mesh, cfg, args.batch),
-        grad_shardings=pshard)
-    jitted = jax.jit(step_fn, in_shardings=(pshard, oshard, None),
-                     out_shardings=(pshard, oshard, None),
-                     donate_argnums=(0, 1))
+    trainer = make_trainer(cfg, mesh, global_batch=args.batch,
+                           seq_len=args.seq_len, lr=args.lr,
+                           grad_accum=args.grad_accum)
 
     # ------------------------------------------------------ 4. init / restore
     ckpt = CheckpointManager(args.ckpt_dir, keep=3, async_write=True)
     start = 0
-    init_opt, _, _ = make_optimizer(cfg.optimizer, lr=args.lr)
     if args.resume and ckpt.latest_step() is not None:
         start = ckpt.latest_step()
-        pabs = abstract_params(pdefs)
-        oabs = abstract_params(odefs)
+        pabs = abstract_params(trainer.pdefs)
+        oabs = abstract_params(trainer.odefs)
         params = ckpt.restore(start, {"params": pabs})["params"]
-        params = jax.tree.map(
-            lambda a, s: jax.device_put(a, s), params, pshard)
+        params = jax.tree.map(jax.device_put, params, trainer.params_sharding)
         opt_state = ckpt.restore(start, {"opt": oabs})["opt"]
-        opt_state = jax.tree.map(lambda a, s: jax.device_put(a, s),
-                                 opt_state, oshard)
+        opt_state = jax.tree.map(jax.device_put, opt_state,
+                                 trainer.opt_sharding)
         feeder.step = start
         print(f"[restore] resumed from step {start} (elastic across meshes)")
     else:
-        params = init_params(jax.random.PRNGKey(0), pdefs)
-        params = jax.tree.map(lambda a, s: jax.device_put(a, s), params, pshard)
-        opt_state = jax.device_put(init_opt(params))
+        params, opt_state = trainer.init_state(seed=0)
 
     # ------------------------------------------------------ 5. train loop
     t0 = time.time()
     losses = []
     for i, raw in enumerate(feeder.batches(args.steps)):
-        batch = make_batch(raw, args.seq_len)
-        params, opt_state, metrics = jitted(params, opt_state, batch)
+        batch = trainer.put_batch(make_batch(raw, args.seq_len))
+        params, opt_state, metrics = trainer.step(params, opt_state, batch)
         step = start + i + 1
         losses.append(float(metrics["loss"]))
         if step % args.log_every == 0:

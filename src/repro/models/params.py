@@ -77,7 +77,9 @@ def logical_to_spec(axes: Sequence[Optional[str]],
 
     A rule value may be a mesh-axis name, a tuple of mesh axes, or None.
     If two dims would map to the same mesh axis, the later dim wins nothing
-    (kept unsharded) — XLA requires each mesh axis used at most once.
+    (kept unsharded) — XLA requires each mesh axis used at most once.  Given
+    ``axis_sizes``, a rule naming an axis the mesh lacks leaves that dim
+    unsharded.
     """
     used: set = set()
     out: List[Any] = []
@@ -87,14 +89,15 @@ def logical_to_spec(axes: Sequence[Optional[str]],
             out.append(None)
             continue
         entries = mesh_ax if isinstance(mesh_ax, tuple) else (mesh_ax,)
-        free = tuple(e for e in entries if e not in used)
+        free = tuple(e for e in entries if e not in used
+                     and (axis_sizes is None or e in axis_sizes))
         if shape is not None and axis_sizes is not None and free:
             # keep the longest divisible prefix of the mesh-axis tuple
             dim = shape[i]
             kept = []
             prod = 1
             for e in free:
-                prod *= axis_sizes.get(e, 1)
+                prod *= axis_sizes[e]
                 if dim % prod == 0:
                     kept.append(e)
                 else:

@@ -602,20 +602,17 @@ class TestPackKernelRoute:
                 np.testing.assert_array_equal(a.data[k], b.data[k])
 
     def test_kernel_failure_falls_back_to_scalar(self, rng):
+        """There is no scalar fallback any more: a kernel failure raises
+        out of the batch, and nothing is emitted."""
         from repro.core.ops_format import PackOp
         op = PackOp(seq_len=32, rows_per_block=4, use_pallas=True)
 
         def boom(*a, **kw):
             raise RuntimeError("kernel down")
         op._pack_kernel = boom
-        items = self._chunks(rng, 3)
-        oracle = PackOp(seq_len=32, rows_per_block=4).run_batch(
-            copy.deepcopy(items))
-        out = op.run_batch(copy.deepcopy(items))
-        assert len(out) == len(oracle)
-        for a, b in zip(oracle, out):
-            for k in a.data:
-                np.testing.assert_array_equal(a.data[k], b.data[k])
+        with pytest.raises(RuntimeError, match="kernel down"):
+            op.process_batch(self._chunks(rng, 3))
+        assert op._block_idx == 0
 
 
 # ---------------------------------------------------------------------------

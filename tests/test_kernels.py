@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ops import flash_attention, gf256_matmul, pack_tokens
+from repro.kernels.ops import bucket, flash_attention, gf256_matmul, pack_tokens
 
 
 class TestGF256Matmul:
@@ -84,3 +84,14 @@ class TestPackTokens:
         assert np.array_equal(np.asarray(t), te)
         assert np.array_equal(np.asarray(s), se)
         assert np.array_equal(np.asarray(p), pe)
+
+
+class TestBucket:
+    def test_pads_at_most_a_quarter_and_keeps_few_shapes(self):
+        sizes = [bucket(n) for n in range(1, 1 << 16)]
+        assert all(b >= max(n, 128) for n, b in zip(range(1, 1 << 16), sizes))
+        assert all(b <= 1.25 * max(n, 128)
+                   for n, b in zip(range(1, 1 << 16), sizes))
+        assert sizes == sorted(sizes)
+        assert len(set(sizes)) <= 4 * 9 + 1   # four per octave above 128
+        assert bucket(1 << 20) == 1 << 20 and bucket((1 << 20) + 1) == 1310720

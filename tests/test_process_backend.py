@@ -142,6 +142,25 @@ class TestPlanShipping:
         finally:
             eng.close()
 
+    def test_process_backend_refuses_device_kernel_op(self, store):
+        """Kernel ops run in the process that owns the chip: shipping one
+        to a forked worker is refused, naming the op."""
+        p = IngestPlan("kern")
+        s1 = select(p)
+        s2 = format_(p, s1, chunk={"target_rows": 256}, serialize="columnar",
+                     erasure={"k": 2, "m": 1, "use_pallas": True})
+        s3 = store_stmt(p, s2, upload=store)
+        create_stage(p, using=[s1, s2, s3], name="main")
+        eng = StreamingRuntimeEngine(store, epoch_items=4, backend="process")
+        try:
+            with pytest.raises(ValueError,
+                               match=r"stage 'main': op 'erasure'.*device "
+                                     r"kernel"):
+                eng.run_stream(p, shard_source(4))
+        finally:
+            eng.close()
+        assert store.committed_epoch_ids() == []
+
 
 # ---------------------------------------------------------------------------
 class TestProcessStreaming:

@@ -236,3 +236,34 @@ class TestDryrunHelpers:
         assert spec == jax.sharding.PartitionSpec("model", "data")
         spec = logical_to_spec(("embed", "heads", None), rules, (576, 9, 64), sizes)
         assert spec == jax.sharding.PartitionSpec("data",)
+
+    def test_sharding_rules_skip_axes_the_mesh_lacks(self):
+        """On a data-only mesh a rule naming "model" leaves the dim whole."""
+        from repro.models.params import logical_to_spec
+        rules = {"vocab": "model", "embed": "data"}
+        spec = logical_to_spec(("vocab", "embed"), rules, (49152, 576),
+                               {"data": 4})
+        assert spec == jax.sharding.PartitionSpec(None, "data")
+
+    def test_importing_dryrun_leaves_xla_flags_alone(self, monkeypatch):
+        import importlib
+        monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
+        import repro.launch.dryrun as dryrun
+        importlib.reload(dryrun)
+        assert os.environ["XLA_FLAGS"] == "--xla_dump_to=/dev/null"
+        dryrun.force_host_devices(8)
+        dryrun.force_host_devices(8)
+        assert os.environ["XLA_FLAGS"] == (
+            "--xla_dump_to=/dev/null --xla_force_host_platform_device_count=8")
+
+
+class TestChipPeaks:
+    def test_v5e_peaks_by_device_kind(self):
+        from repro.launch.mesh import chip_peaks
+        p = chip_peaks("TPU v5 lite")
+        assert p.flops_bf16 == 197e12 and p.hbm_bw == 819e9
+
+    def test_unknown_kind_is_an_error(self):
+        from repro.launch.mesh import chip_peaks
+        with pytest.raises(KeyError, match="no published peaks"):
+            chip_peaks("cpu")
