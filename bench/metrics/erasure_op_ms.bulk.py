@@ -1,0 +1,14 @@
+"""Host time of the erasure operator per epoch of the bulk ingest cell
+(ms): self time of the ``ib.op.ErasureOp`` spans (stripe staging and the
+parity items; the kernel call is its child and left out), median over the
+window's whole epochs."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import _spans  # noqa: E402
+
+
+def read(rec):
+    return _spans.epoch_median(rec, lambda ep: ep.self_ms("ib.op.ErasureOp"))

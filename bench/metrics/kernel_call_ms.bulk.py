@@ -1,0 +1,14 @@
+"""Time of the ingest kernels' calls per epoch of the bulk ingest cell
+(ms): the ``ib.kernel.*`` spans, each from the copy in through the launch
+and the device work to the result held on the host, median over the
+window's whole epochs."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import _spans  # noqa: E402
+
+
+def read(rec):
+    return _spans.epoch_median(rec, lambda ep: ep.total_ms("ib.kernel.*"))

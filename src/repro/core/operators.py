@@ -26,6 +26,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import (Any, Deque, Dict, Iterable, Iterator, List, Optional,
                     Sequence, Tuple)
 
+from .. import tracing
 from .items import Granularity, IngestItem
 
 
@@ -80,9 +81,9 @@ class IngestOp:
         self._finalized_ok = False  # runtime FT tracks finalize success (Sec. VI-C)
         # test hook: fail the next N process() calls (fault injection)
         self._fail_next: int = 0
-        # milliseconds spent inside vectorized kernels (batch tier); the
-        # runtime diffs this around a batch block to charge RunReport.kernel_ms
-        self.kernel_ms_total: float = 0.0
+        # device kernel launches (batch tier); the runtime diffs this around
+        # a batch block to charge RunReport.kernel_calls
+        self.kernel_calls: int = 0
 
     # ------------------------------------------------------------ iterator API
     def initialize(self) -> None:
@@ -286,6 +287,15 @@ class MaterializeOp(IngestOp):
         yield item
 
 
+def op_span(op: IngestOp, items: Sequence[IngestItem]):
+    """The ``ib.op.<class>`` span around one operator call, with the rows
+    it takes in (counted only while spans are recorded)."""
+    if not tracing.recording():
+        return tracing.NOOP
+    return tracing.span("ib.op." + type(op).__name__,
+                        rows=sum(it.nrows() for it in items))
+
+
 def run_ops_batched(ops: Sequence[IngestOp], items: Sequence[IngestItem]
                     ) -> Tuple[List[IngestItem], Dict[str, Any]]:
     """Execute one batch-mode pipeline block (ISSUE 7).
@@ -298,22 +308,23 @@ def run_ops_batched(ops: Sequence[IngestOp], items: Sequence[IngestItem]
     machinery applies unchanged.
 
     Returns ``(out, stats)`` with ``vectorized_rows`` (rows entering the
-    block), ``batch_fallbacks`` and ``kernel_ms`` (vectorized-kernel time the
-    block's ops accumulated).
+    block), ``batch_fallbacks`` and ``kernel_calls`` (device kernel
+    launches the block's ops made).
     """
     rows = sum(it.nrows() for it in items)
-    kernel_before = sum(op.kernel_ms_total for op in ops)
+    calls_before = sum(op.kernel_calls for op in ops)
     fallbacks = 0
     out: List[IngestItem] = list(items)
     for op in ops:
-        try:
-            out = op.run_batch(out)
-        except BatchFallback:
-            fallbacks += 1
-            out = op.run(out)
+        with op_span(op, out):
+            try:
+                out = op.run_batch(out)
+            except BatchFallback:
+                fallbacks += 1
+                out = op.run(out)
     return out, {"vectorized_rows": rows, "batch_fallbacks": fallbacks,
-                 "kernel_ms": sum(op.kernel_ms_total for op in ops)
-                 - kernel_before}
+                 "kernel_calls": sum(op.kernel_calls for op in ops)
+                 - calls_before}
 
 
 # ----------------------------------------------------------------------------

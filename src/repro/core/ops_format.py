@@ -6,11 +6,11 @@ partitioning) or be reordered by the user (global vs per-chunk sort).
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from .. import tracing
 from ..layouts import serialize_block
 from .items import Columns, Granularity, IngestItem, concat_columns, num_rows, take_rows
 from .operators import IngestOp, OpMode, register_op
@@ -362,11 +362,12 @@ class PackOp(IngestOp):
         table = np.zeros((2, R), np.int32)
         table[0, :len(starts)] = starts
         table[1, :len(lens)] = lens
-        t0 = time.perf_counter()
-        toks, mask, _ = self._pack_kernel(flat, table[0], table[1], S,
-                                          pad_id=self.pad_id)
-        toks, mask = np.asarray(toks), np.asarray(mask)
-        self.kernel_ms_total += (time.perf_counter() - t0) * 1000.0
+        # the span holds the launch, the device work and the copy back
+        with tracing.span("ib.kernel.pack_tokens"):
+            toks, mask, _ = self._pack_kernel(flat, table[0], table[1], S,
+                                              pad_id=self.pad_id)
+            toks, mask = np.asarray(toks), np.asarray(mask)
+        self.kernel_calls += 1
         out_rows: List[Dict[str, np.ndarray]] = []
         for r, row in enumerate(all_rows):
             pos = np.zeros(S, np.int32)

@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
+from .. import tracing
 from ..erasure import ReedSolomon
 from ..layouts import SerializedBlock, serialize_block
 from .items import Granularity, IngestItem
@@ -169,7 +170,8 @@ class ErasureOp(IngestOp):
                    for i in range(0, len(pending), self.k)]
         views = [[self._payload_view(it) for it in s] for s in stripes]
         encoded = self.rs.encode_payload_batch(views)
-        self.kernel_ms_total += self.rs.last_kernel_s * 1000.0
+        if self.rs.use_pallas:
+            self.kernel_calls += 1
         out: List[IngestItem] = []
         for stripe, (parity, pad_len) in zip(stripes, encoded):
             out.extend(self._emit_encoded(stripe, parity, pad_len))
@@ -286,6 +288,7 @@ class UploadOp(IngestOp):
                 "is_parity": item.meta.get("is_parity", False),
             })
         entries = self.store.put_block_batch(reqs)
+        tracing.annotate(bytes=sum(e.nbytes for e in entries))
         return [it.with_label(self.name, e.node)
                 for it, e in zip(prepped, entries)]
 

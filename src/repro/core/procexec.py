@@ -292,7 +292,7 @@ def _run_stage_ops(sp: StagePlan, items: List[IngestItem],
     like the thread backend's node clones."""
     stats: Dict[str, Any] = {"op_failures": {}, "dummy": [],
                              "vectorized_rows": 0, "batch_fallbacks": 0,
-                             "kernel_ms": 0.0}
+                             "kernel_calls": 0}
     counts: Dict[int, int] = defaultdict(int)
     current = items
     blocks = sp.pipeline_blocks or [[i] for i in range(len(sp.ops))]
@@ -316,7 +316,7 @@ def _run_stage_ops(sp: StagePlan, items: List[IngestItem],
                         [sp.ops[oi] for oi in block], out)
                     stats["vectorized_rows"] += bstats["vectorized_rows"]
                     stats["batch_fallbacks"] += bstats["batch_fallbacks"]
-                    stats["kernel_ms"] += bstats["kernel_ms"]
+                    stats["kernel_calls"] += bstats["kernel_calls"]
                 else:
                     for oi in block:
                         if injections.get(oi, 0) > 0:

@@ -28,6 +28,7 @@
 """
 from __future__ import annotations
 
+import contextvars
 import itertools
 import os
 import pickle
@@ -40,12 +41,13 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple, Union
 
+from .. import tracing
 from .exchange import (PartitionExchange, build_manifest, columnar_file_name,
                        exchange_file_name, partition_items, resident_file_name,
                        unlink_segment, write_columnar_file,
                        write_partition_file)
 from .items import ColumnarBatch, IngestItem, items_nbytes
-from .operators import (IngestOp, OperatorFailure, PassThroughOp,
+from .operators import (IngestOp, OperatorFailure, PassThroughOp, op_span,
                         run_ops_batched)
 from .optimizer import IngestionOptimizer
 from .plan import (IngestPlan, StagePlan, failed_op_index, route_items,
@@ -137,7 +139,7 @@ class RunReport:
     # --- batch operator tier (ISSUE 7): optimizer-selected vectorization ----
     vectorized_rows: int = 0           # rows that entered batch-mode blocks
     batch_fallbacks: int = 0           # ops that dropped back to the scalar path
-    kernel_ms: float = 0.0             # time inside vectorized encode kernels
+    kernel_calls: int = 0              # kernel launches in batch blocks
     # --- columnar data plane (ISSUE 10): column buffers across stage edges --
     columnar_rounds: int = 0           # exchange rounds with >=1 columnar part
     columnar_bytes: int = 0            # partition bytes that crossed columnar
@@ -174,6 +176,10 @@ class _ExecutorLane:
 
     def submit(self, fn: Callable, *args: Any) -> Future:
         fut: Future = Future()
+        if tracing.recording():
+            # the job runs in the submitter's context: spans it opens nest
+            # under the span open where it was submitted
+            fn, args = contextvars.copy_context().run, (fn,) + args
         self.jobs.put((fn, args, fut))
         return fut
 
@@ -1428,7 +1434,7 @@ class RuntimeEngine:
                             "vectorized_rows", 0)
                         report.batch_fallbacks += stats.get(
                             "batch_fallbacks", 0)
-                        report.kernel_ms += stats.get("kernel_ms", 0.0)
+                        report.kernel_calls += stats.get("kernel_calls", 0)
                 else:
                     payload = res
                 if (produce is not None and isinstance(payload, dict)
@@ -1682,7 +1688,7 @@ class RuntimeEngine:
                         with rlock:
                             report.vectorized_rows += bstats["vectorized_rows"]
                             report.batch_fallbacks += bstats["batch_fallbacks"]
-                            report.kernel_ms += bstats["kernel_ms"]
+                            report.kernel_calls += bstats["kernel_calls"]
                     else:
                         for oi in block:
                             op = sp.ops[oi]
@@ -1692,7 +1698,8 @@ class RuntimeEngine:
                                 faults.op_failures[key] -= 1
                                 raise OperatorFailure(
                                     f"injected @ {sp.name}[{oi}]")
-                            out = op.run(out)
+                            with op_span(op, out):
+                                out = op.run(out)
                     current = out
                     break
                 except OperatorFailure as e:

@@ -38,6 +38,7 @@ from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from .. import tracing
 from ..layouts import SerializedBlock
 from .items import Granularity, IngestItem, Label
 
@@ -297,6 +298,10 @@ class DataStore:
         blocks), not an O(store) manifest rewrite): a fully-written line is a
         committed epoch, a torn line is not — ``flush_manifest`` periodically
         folds the journal into the snapshot."""
+        with tracing.span("ib.store.commit"):
+            return self._commit_epoch(epoch, n_items)
+
+    def _commit_epoch(self, epoch: int, n_items: int) -> EpochEntry:
         deadline = time.monotonic() + self.COMMIT_SEQUENCE_TIMEOUT_S
         with self._commit_cv:
             if epoch in self.epochs:

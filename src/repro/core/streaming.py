@@ -55,6 +55,7 @@ from dataclasses import dataclass, field
 from typing import (Any, Dict, Iterable, Iterator, List, Optional, Sequence,
                     Tuple, Union)
 
+from .. import tracing
 from .items import IngestItem
 from .liveness import LivenessMonitor
 from .optimizer import IngestionOptimizer, split_pipeline_segments
@@ -225,9 +226,9 @@ class StreamReport:
         """Batched blocks that fell back to the scalar iterator path."""
         return sum(e.run.batch_fallbacks for e in self.epochs)
 
-    def kernel_ms(self) -> float:
-        """Milliseconds spent inside erasure/encode kernels across epochs."""
-        return sum(e.run.kernel_ms for e in self.epochs)
+    def kernel_calls(self) -> int:
+        """Kernel launches in batch blocks across committed epochs."""
+        return sum(e.run.kernel_calls for e in self.epochs)
 
     def source_reissues(self) -> int:
         """Descriptors re-issued to survivors after a reader death."""
@@ -560,7 +561,8 @@ class _EpochCommitter:
             if self._error is not None:
                 continue   # drain remaining jobs so submit() never deadlocks
             try:
-                self._commit_job(job)
+                with tracing.span("ib.epoch", epoch=job.eid):
+                    self._commit_job(job)
             except BaseException as e:
                 self._error = e
 
@@ -1245,60 +1247,63 @@ class StreamingRuntimeEngine(RuntimeEngine):
         publish point, so a replayed epoch can neither lose items (the full
         input batch — items or shard descriptors — is retained until commit)
         nor double-commit (``begin_epoch`` refuses committed ids)."""
-        items_in = sum(len(v) for v in batch.values())
-        n_descs = items_in if source is not None else 0
-        pushed_bytes = (0 if source is not None else sum(
-            it.nbytes() for v in batch.values() for it in v))
-        t_cut = time.time()
-        attempts = 0
-        reissues = 0
-        while True:
-            attempts += 1
-            live = [n for n in self.nodes if self.alive[n]]
-            if not live:
-                raise RuntimeError("all nodes failed")
-            if source is not None:
-                reissues += self._count_lost(batch, live)
-            node_sources = self._redistribute(batch, live)
-            batch = node_sources   # keep replay bookkeeping per-assignment
+        with tracing.span("ib.epoch", epoch=eid):
+            items_in = sum(len(v) for v in batch.values())
+            n_descs = items_in if source is not None else 0
+            pushed_bytes = (0 if source is not None else sum(
+                it.nbytes() for v in batch.values() for it in v))
+            t_cut = time.time()
+            attempts = 0
+            reissues = 0
+            while True:
+                attempts += 1
+                live = [n for n in self.nodes if self.alive[n]]
+                if not live:
+                    raise RuntimeError("all nodes failed")
+                if source is not None:
+                    reissues += self._count_lost(batch, live)
+                node_sources = self._redistribute(batch, live)
+                batch = node_sources   # keep replay bookkeeping per-assignment
 
-            # injected mid-epoch deaths for this epoch index -> die after the
-            # first stage of the attempt (blocks already staged get aborted)
-            ef = FaultInjection(op_failures=faults.op_failures)
-            for n, at_epoch in faults.node_death_in_epoch.items():
-                if at_epoch == epoch_index and self.alive.get(n):
-                    ef.node_death_after_stage[n] = stage_plans[0].name
-            for (n, at_epoch), stname in faults.node_death_at.items():
-                if at_epoch == epoch_index and self.alive.get(n):
-                    ef.node_death_after_stage[n] = stname
+                # injected mid-epoch deaths for this epoch index -> die after
+                # the first stage of the attempt (blocks already staged get
+                # aborted)
+                ef = FaultInjection(op_failures=faults.op_failures)
+                for n, at_epoch in faults.node_death_in_epoch.items():
+                    if at_epoch == epoch_index and self.alive.get(n):
+                        ef.node_death_after_stage[n] = stage_plans[0].name
+                for (n, at_epoch), stname in faults.node_death_at.items():
+                    if at_epoch == epoch_index and self.alive.get(n):
+                        ef.node_death_after_stage[n] = stname
 
-            self.store.begin_epoch(eid)
-            ereport = RunReport()
-            if attempts > 1:
-                # sequential mode always replays wholesale: the full DAG ran
-                # under one _execute, so a death loses the epoch's exchange
-                ereport.replayed_rows = _unit_rows(
-                    it for v in node_sources.values() for it in v)
-            if source is not None:
-                ereport.source_descriptors = n_descs
-                ereport.source_reissues = reissues
-            else:
-                ereport.source_coordinator_bytes = pushed_bytes
-            try:
-                self._execute(stage_plans, node_sources, ef, ereport,
-                              self.alive, on_node_death="raise", epoch=eid,
-                              node_set=live, source=source)
-            except NodeFailure as e:
-                self.store.abort_epoch(eid)
-                self._note_death(str(e), eid, sreport, queues)
-                continue
-            if source is not None:
-                items_in = ereport.source_items
-            entry = self.store.commit_epoch(eid, n_items=items_in)
-            return EpochReport(epoch=eid, items_in=items_in,
-                               n_blocks=entry.n_blocks, attempts=attempts,
-                               commit_latency_s=time.time() - t_cut,
-                               run=ereport)
+                self.store.begin_epoch(eid)
+                ereport = RunReport()
+                if attempts > 1:
+                    # sequential mode always replays wholesale: the full DAG
+                    # ran under one _execute, so a death loses the epoch's
+                    # exchange
+                    ereport.replayed_rows = _unit_rows(
+                        it for v in node_sources.values() for it in v)
+                if source is not None:
+                    ereport.source_descriptors = n_descs
+                    ereport.source_reissues = reissues
+                else:
+                    ereport.source_coordinator_bytes = pushed_bytes
+                try:
+                    self._execute(stage_plans, node_sources, ef, ereport,
+                                  self.alive, on_node_death="raise", epoch=eid,
+                                  node_set=live, source=source)
+                except NodeFailure as e:
+                    self.store.abort_epoch(eid)
+                    self._note_death(str(e), eid, sreport, queues)
+                    continue
+                if source is not None:
+                    items_in = ereport.source_items
+                entry = self.store.commit_epoch(eid, n_items=items_in)
+                return EpochReport(epoch=eid, items_in=items_in,
+                                   n_blocks=entry.n_blocks, attempts=attempts,
+                                   commit_latency_s=time.time() - t_cut,
+                                   run=ereport)
 
 
 def stream_ingest(plan: IngestPlan,

@@ -19,6 +19,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..core import (DataAccess, DataStore, IngestItem, IngestPlan, create_stage,
                     format_, ingest, select, store)
 from ..core.items import Columns
@@ -116,7 +117,21 @@ class BlockFeeder:
         After every yielded batch, ``(self.step, self.offset)`` is the exact
         resume point: a fresh feeder constructed with
         ``start_step=step, start_offset=offset`` continues the stream with
-        identical batches — no carry rows are lost or replayed."""
+        identical batches — no carry rows are lost or replayed.
+
+        Building each batch (block reads, decode, concatenation) is one
+        ``ib.feeder.batch`` span, closed before the batch is handed out."""
+        built = self._build_batches(num_steps)
+        while True:
+            with tracing.span("ib.feeder.batch"):
+                out = next(built, None)
+                if out is None:
+                    return
+                tracing.annotate(rows=len(out[self.fields[0]]))
+            yield out
+
+    def _build_batches(self, num_steps: int
+                       ) -> Iterator[Dict[str, np.ndarray]]:
         if not self.my_blocks:
             return
         buf: Dict[str, List[np.ndarray]] = {f: [] for f in self.fields}

@@ -6,11 +6,11 @@ Recovery:  any k surviving rows of [I; C] are invertible — solve for the
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from .gf256 import GF256
 
 
@@ -21,7 +21,6 @@ class ReedSolomon:
         self.k, self.m = k, m
         self.C = GF256.cauchy_matrix(m, k)  # (m, k)
         self.use_pallas = use_pallas
-        self.last_kernel_s = 0.0   # encode time of the last batch call
         self._pallas_matmul = None
         if use_pallas:
             from ..kernels import ops as gf_ops  # lazy: jax import
@@ -74,14 +73,13 @@ class ReedSolomon:
         the scalar path stays the correctness oracle.
 
         Returns ``[(parity (m, L_s) view, L_s), ...]``; the views alias the
-        shared accumulator.  Encode time lands in ``self.last_kernel_s``.
+        shared accumulator.
         """
         Ls = [self.stripe_pad(ps) for ps in stripes]
         offs = [0]
         for L in Ls:
             offs.append(offs[-1] + L)
         total = offs[-1]
-        t0 = time.perf_counter()
         if self._pallas_matmul is not None:
             # bucketed width: the kernel compiles for a few shapes, not one
             # per batch; the zero columns encode to parity nobody reads
@@ -92,8 +90,11 @@ class ReedSolomon:
                 for j, p in enumerate(ps):
                     data[j, o:o + len(p)] = p
             from ..core.items import as_device_array  # lazy: jax import
-            parity = np.asarray(
-                self._pallas_matmul(self.C, as_device_array(data)))
+            # the span holds the copy in, the launch, the device work and
+            # the copy back
+            with tracing.span("ib.kernel.gf256_matmul"):
+                parity = np.asarray(
+                    self._pallas_matmul(self.C, as_device_array(data)))
         else:
             parity = np.zeros((self.m, total), dtype=np.uint8)
             for si, ps in enumerate(stripes):
@@ -101,7 +102,6 @@ class ReedSolomon:
                 for j, p in enumerate(ps):
                     for i in range(self.m):
                         GF256.xor_mul_into(parity[i, o:], int(self.C[i, j]), p)
-        self.last_kernel_s = time.perf_counter() - t0
         return [(parity[:, offs[s]:offs[s] + Ls[s]], Ls[s])
                 for s in range(len(stripes))]
 

@@ -24,6 +24,8 @@ from typing import Any, Callable, Dict
 import jax
 import numpy as np
 
+from .. import tracing
+
 BATCH_FIELDS = ("tokens", "labels", "segments", "positions")
 
 
@@ -39,18 +41,19 @@ def build_mesh(spec: str):
 
 def make_batch(raw, seq_len: int, pad_id: int = 0):
     """BlockFeeder fields -> model batch (next-token labels from tokens)."""
-    toks = raw["tokens"].astype(np.int32)
-    seg = raw["segment_ids"].astype(np.int32)
-    pos = raw["positions"].astype(np.int32)
-    mask = raw["loss_mask"].astype(np.int32)
-    labels = np.concatenate([toks[:, 1:], np.full((toks.shape[0], 1), -1,
-                                                  np.int32)], axis=1)
-    # don't predict across packing boundaries
-    labels = np.where((seg == np.concatenate(
-        [seg[:, 1:], np.zeros((seg.shape[0], 1), np.int32)], axis=1))
-        & (mask > 0), labels, -1)
-    return {"tokens": toks, "labels": labels, "segments": seg,
-            "positions": pos}
+    with tracing.span("ib.train.make_batch"):
+        toks = raw["tokens"].astype(np.int32)
+        seg = raw["segment_ids"].astype(np.int32)
+        pos = raw["positions"].astype(np.int32)
+        mask = raw["loss_mask"].astype(np.int32)
+        labels = np.concatenate([toks[:, 1:], np.full((toks.shape[0], 1), -1,
+                                                      np.int32)], axis=1)
+        # don't predict across packing boundaries
+        labels = np.where((seg == np.concatenate(
+            [seg[:, 1:], np.zeros((seg.shape[0], 1), np.int32)], axis=1))
+            & (mask > 0), labels, -1)
+        return {"tokens": toks, "labels": labels, "segments": seg,
+                "positions": pos}
 
 
 @dataclass
@@ -78,8 +81,9 @@ class Trainer:
 
     def put_batch(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
         """A host batch -> device arrays laid out by ``batch_sharding``."""
-        return {k: jax.device_put(batch[k], self.batch_sharding[k])
-                for k in BATCH_FIELDS}
+        with tracing.span("ib.train.put_batch"):
+            return {k: jax.device_put(batch[k], self.batch_sharding[k])
+                    for k in BATCH_FIELDS}
 
 
 def make_trainer(cfg, mesh, *, global_batch: int, seq_len: int, lr: float,

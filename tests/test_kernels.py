@@ -95,3 +95,18 @@ class TestBucket:
         assert sizes == sorted(sizes)
         assert len(set(sizes)) <= 4 * 9 + 1   # four per octave above 128
         assert bucket(1 << 20) == 1 << 20 and bucket((1 << 20) + 1) == 1310720
+
+
+class TestProgramNames:
+    """The device trace names each kernel's program by its jitted wrapper;
+    kernel time is read from the trace under these names."""
+
+    def test_ingest_kernels_lower_to_their_named_modules(self):
+        spec = jax.ShapeDtypeStruct
+        pack = pack_tokens.lower(spec((1024,), jnp.int32),
+                                 spec((8,), jnp.int32), spec((8,), jnp.int32),
+                                 128)
+        gf = gf256_matmul.lower(spec((3, 4), jnp.uint8),
+                                spec((4, 1024), jnp.uint8))
+        assert "module @jit_pack_tokens" in pack.as_text()
+        assert "module @jit_gf256_matmul" in gf.as_text()
