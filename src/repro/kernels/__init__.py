@@ -1,7 +1,8 @@
-"""Pallas TPU kernels for the ingest/serve hot spots (DESIGN.md §6).
+"""Pallas TPU kernels for the ingest hot spots and the train step's attention.
 
-Each kernel: <name>.py (pl.pallas_call + BlockSpec tiling), a pure oracle in
-ref.py, and a jit'd wrapper in ops.py (interpret=True off-TPU).
+Each kernel: <name>.py (a Pallas call and its tiling), a pure oracle (ref.py;
+for flash attention, models.attention.attention_naive), and a jit'd wrapper
+in ops.py (interpret=True off-TPU).
 """
 from .ops import flash_attention, gf256_matmul, pack_tokens
 

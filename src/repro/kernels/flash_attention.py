@@ -1,15 +1,39 @@
-"""Pallas TPU kernel: blockwise flash attention (online softmax).
+"""Pallas TPU flash attention for the train step: causal, packed rows, GQA.
 
-The serving-path hot spot.  Unlike the pure-jnp chunked attention in
-models/attention.py (which materializes (Sq, bk) logits tiles in HBM when Sq
-is large), this kernel tiles BOTH the query and key dimensions so the live
-working set is (bq, d) + (bk, d) + (bq, bk) in VMEM — the standard
-flash-attention memory shape, adapted to the TPU hierarchy (HBM -> VMEM ->
-VREG, MXU-aligned 128-multiple tiles).
+The training self-attention's score, softmax and value product, forward and
+backward, as Pallas kernels under one ``jax.custom_vjp``: logits and
+probabilities exist only as VMEM tiles, never in HBM.  Built on JAX's splash
+attention (``jax.experimental.pallas.ops.tpu.splash_attention``): a forward
+kernel that also writes the log-sum-exp, and dq and dkv backward kernels
+that recompute the probabilities from it.  On the device the three kernels
+are named ``splash_mha_fwd_segmented_residuals``,
+``splash_mha_dq_segmented_no_residuals`` and
+``splash_mha_dkv_segmented_no_residuals``.
 
-Layout: grid = (B*H, Sq//bq); the kv loop is a fori_loop inside the kernel so
-only causally-needed kv blocks are visited.  GQA is handled by the wrapper
-(kv heads repeated logically via index maps, never materialized).
+Mask.  Key j is visible to query i when both lie in the same segment and
+j <= i, both computed inside the kernel from the segment ids and the
+indices; KV blocks wholly above the diagonal are skipped.  The model's mask
+(``models.attention._mask``) is ``q_seg == k_seg & k_seg > 0 & q_pos >=
+k_pos``.  In a packed row each piece has an id of its own, and positions
+count from 0 within it and rise with the index, so within a segment
+``q_pos >= k_pos`` is ``i >= j``: the two masks agree at every real query
+(segment > 0).  Padding queries (segment 0) attend the padding keys at or
+before them, where the model's mask hides every key from them and they
+average all keys.  No real query attends a padding key, and padding carries
+no loss, so the loss and its gradients are those of the model's mask in
+exact arithmetic.  No query attends across segments.
+
+Precision.  q, k and v keep their dtype (bf16 in training).  Logits, the
+running max and sum, and every accumulator are float32.  The scale
+``head_dim ** -0.5`` is folded into q; the head sizes the kernel takes
+(``HEAD_DIMS``) make it a power of two, so the fold rounds nothing.  The
+forward's value product takes the probabilities in float32; the backward
+rounds the probabilities and their gradient to the input dtype only as
+operands of its dv, dq and dk products, where the jnp path's backward
+rounds them too.
+
+GQA: query head h reads key/value head ``h // (H // KV)`` through the
+kernels' index maps; repeated K/V are never materialised.
 """
 from __future__ import annotations
 
@@ -17,74 +41,51 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-NEG_INF = -1.0e30
-
-
-def _kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int, scale: float,
-            causal: bool):
-    qi = pl.program_id(1)
-    Sk = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32) * scale            # (bq, d)
-
-    def body(j, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        s = q @ k.T                                      # (bq, bk) on the MXU
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_cur = l_prev * alpha + p.sum(axis=-1)
-        acc = acc * alpha[:, None] + p @ v
-        return acc, m_cur, l_cur
-
-    d = q_ref.shape[-1]
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    # causal: only visit kv blocks up to (and including) this q block
-    n_blocks = (qi + 1) * bq // bk if causal else Sk // bk
-    acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
-    o_ref[0, ...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+# head sizes whose scale head_dim ** -0.5 is a power of two
+HEAD_DIMS = (64, 256)
+# q and kv block of every kernel, forward and backward (at most the sequence)
+BLOCK = 512
+# splash blocks must tile the MXU's lanes
+_LANES = 128
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, bq: int = 512, bk: int = 512,
-                    interpret: bool = False) -> jax.Array:
-    """q (B, Sq, H, d), k/v (B, Sk, KV, d) -> (B, Sq, H, d).
+def block_size(seq_len: int) -> int:
+    return min(seq_len, BLOCK)
 
-    GQA: q head h reads kv head h // (H // KV) via the kv index map."""
-    B, Sq, H, d = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
-    g = H // KV
-    bq = min(bq, Sq)
-    bk = min(bk, Sk)
-    assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
-    scale = d ** -0.5
 
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * KV, Sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * KV, Sk, d)
+def supports(seq_len: int, head_dim: int) -> bool:
+    """Whether the kernel takes a sequence of ``seq_len`` at ``head_dim``."""
+    b = block_size(seq_len)
+    return head_dim in HEAD_DIMS and b % _LANES == 0 and seq_len % b == 0
 
-    def kv_map(bh, qi):
-        return (bh // g, 0, 0)   # collapse q-head to its kv head
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, bq=bq, bk=bk, scale=scale, causal=causal),
-        grid=(B * H, Sq // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, Sk, d), kv_map),
-            pl.BlockSpec((1, Sk, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, d), q.dtype),
-        interpret=interpret,
-    )(qf, kf, vf)
-    return out.reshape(B, H, Sq, d).transpose(0, 2, 1, 3)
+@functools.lru_cache(maxsize=None)
+def _kernel(seq_len: int, n_heads: int, b: int, interpret: bool):
+    mask = splash.MultiHeadMask([splash.CausalMask((seq_len, seq_len))]
+                                * n_heads)
+    blocks = splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
+        block_q_dq=b, block_kv_dq=b)
+    return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    seg: jax.Array, *, interpret: bool = False) -> jax.Array:
+    """q (B, S, H, Dh), k/v (B, S, KV, Dh), seg (B, S) -> (B, S, H, Dh).
+
+    ``seg`` holds each token's segment id (0 = padding); see the module
+    docstring for the mask and what it means for padding queries."""
+    B, S, H, Dh = q.shape
+    assert supports(S, Dh), (S, Dh)
+    kernel = _kernel(S, H, block_size(S), interpret)
+    q = q * jnp.asarray(Dh ** -0.5, q.dtype)
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)
+    seg = seg.astype(jnp.int32)
+    out = jax.vmap(lambda q, k, v, s: kernel(
+        q, k, v, segment_ids=splash.SegmentIds(s, s)))(
+            heads_first(q), heads_first(k), heads_first(v), seg)
+    return heads_first(out)

@@ -2,9 +2,14 @@
 
 ``interpret`` defaults to the backend: the real kernels on a TPU, the Pallas
 interpreter on the CPU (tests).  Any other backend is an error — a run that
-lost its chip must not pass in interpret mode.  The dry-run/roofline path
-stays pure XLA (Pallas custom-calls report no FLOPs to cost_analysis —
-DESIGN.md §6); kernels are opt-in at run time.
+lost its chip must not pass in interpret mode.
+
+Where each kernel runs: the ingest plan's pack and erasure operators take
+``pack_tokens`` and ``gf256_matmul`` when built with ``use_pallas``; the
+train step's self-attention takes ``flash_attention`` on a TPU wherever the
+kernel applies (``models.model._attention_path``).  The dry-run cost path
+(``ModelConfig.unroll_scans``) stays pure XLA: Pallas custom calls report no
+FLOPs to XLA's ``cost_analysis``.
 """
 from __future__ import annotations
 
@@ -43,12 +48,11 @@ def gf256_matmul(code, data, *, block_n: int = 2048, interpret: bool = None):
     return _gf256(code, data, block_n=block_n, interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
-                    bk: int = 512, interpret: bool = None):
+@partial(jax.jit, static_argnames=("interpret",))
+def flash_attention(q, k, v, seg, *, interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
-    return _flash(q, k, v, causal=causal, bq=bq, bk=bk, interpret=interpret)
+    return _flash(q, k, v, seg, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("seq_len", "pad_id", "interpret"))

@@ -1,8 +1,6 @@
-"""Pure-jnp/numpy oracles for every Pallas kernel (the allclose targets)."""
+"""Numpy oracles for the ingest kernels (the exact-match targets)."""
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..erasure.gf256 import GF256
@@ -20,25 +18,6 @@ def gf256_matmul_ref(code: np.ndarray, data: np.ndarray) -> np.ndarray:
             acc ^= GF256.mul(np.full(N, code[p, k], np.uint8), data[k])
         out[p] = acc
     return out
-
-
-# ------------------------------------------------------- flash attention
-def flash_attention_ref(q, k, v, *, causal: bool = True) -> jax.Array:
-    """Dense softmax attention oracle (fp32 math).  q (B,Sq,H,d),
-    k/v (B,Sk,KV,d) with GQA repeat."""
-    B, Sq, H, d = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
-    g = H // KV
-    kr = jnp.repeat(k, g, axis=2)
-    vr = jnp.repeat(v, g, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   kr.astype(jnp.float32)) * (d ** -0.5)
-    if causal:
-        mask = jnp.tril(jnp.ones((Sq, Sk), bool), Sk - Sq)
-        s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(jnp.float32))
-    return o.astype(q.dtype)
 
 
 # ------------------------------------------------------------ pack tokens

@@ -1,8 +1,9 @@
 """Attention mixers: GQA full/chunked/windowed, cross-attention, decode.
 
-All functions are pure jnp (the dry-run/roofline path); the Pallas
-flash-attention kernel in kernels/flash_attention is an opt-in drop-in for
-real-TPU serving (DESIGN.md §6).
+All functions are pure jnp: XLA's cost analysis sees their FLOPs, and they
+run wherever the Pallas flash-attention kernel (kernels/flash_attention)
+does not.  On a TPU the causal self-attention without a window takes that
+kernel instead of ``attention_chunked`` (``models.model._attention_path``).
 
 Conventions:
   q: (B, Sq, H, Dh)   k/v: (B, Sk, KV, Dh)   H = KV * q_per_kv

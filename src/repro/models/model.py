@@ -19,11 +19,14 @@ shard them exactly like parameters.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops as kernel_ops
+from ..kernels.flash_attention import supports as flash_supports
 from . import attention as attn
 from .config import ModelConfig
 from .layers import embed, embedding_defs, mlp, mlp_defs, rmsnorm, rmsnorm_defs, unembed
@@ -125,6 +128,32 @@ def _pin_w(constrain, name: str, w: jax.Array) -> jax.Array:
     return constrain(name, w) if constrain is not None else w
 
 
+#: self-attention calls traced, by the path each was lowered to
+#: ("kernel", "chunked", "naive", "local"); counted at trace time, so a
+#: scanned layer counts once per trace of the step
+ATTENTION_PATHS: Counter = Counter()
+
+
+def _attention_path(cfg: ModelConfig, window: Optional[int], S: int) -> str:
+    """The path of one causal self-attention call over ``S`` tokens.
+
+    The Pallas flash kernel takes it on a TPU that the process drives
+    alone (XLA cannot partition a Pallas call over a mesh), without a
+    window, at a sequence and head size the kernel supports, and off the
+    dry-run cost path (``unroll_scans``: XLA's cost analysis sees no FLOPs
+    in a Pallas call).  Everywhere else the jnp paths run as they always
+    have."""
+    if window is not None and S % window == 0 and S // window >= 2:
+        return "local"
+    if cfg.attn_impl != "chunked":
+        return "naive"
+    if (jax.default_backend() == "tpu" and jax.device_count() == 1
+            and window is None and not cfg.unroll_scans
+            and flash_supports(S, cfg.head_dim)):
+        return "kernel"
+    return "chunked" if S > cfg.attn_chunk else "naive"
+
+
 def _self_attention(cfg: ModelConfig, kind: str, p: Dict[str, jax.Array],
                     x: jax.Array, seg: jax.Array, pos: jax.Array,
                     constrain=None) -> jax.Array:
@@ -135,9 +164,13 @@ def _self_attention(cfg: ModelConfig, kind: str, p: Dict[str, jax.Array],
     q = attn.rope(q, pos, cfg.rope_theta)
     k = attn.rope(k, pos, cfg.rope_theta)
     window = cfg.window if kind in ("swa", "local") else None
-    if window is not None and S % window == 0 and S // window >= 2:
+    path = _attention_path(cfg, window, S)
+    ATTENTION_PATHS[path] += 1
+    if path == "local":
         o = attn.attention_local(q, k, v, pos, pos, seg, seg, window=window)
-    elif cfg.attn_impl == "chunked" and S > cfg.attn_chunk:
+    elif path == "kernel":
+        o = kernel_ops.flash_attention(q, k, v, seg)
+    elif path == "chunked":
         o = attn.attention_chunked(q, k, v, pos, pos, seg, seg,
                                    chunk=cfg.attn_chunk, window=window,
                                    unroll=cfg.unroll_scans,

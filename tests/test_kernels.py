@@ -1,11 +1,16 @@
 """Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ops import bucket, flash_attention, gf256_matmul, pack_tokens
+from repro.kernels.ops import bucket, gf256_matmul, pack_tokens
+
+# the module (the package exports its entry point under the same name)
+flash = importlib.import_module("repro.kernels.flash_attention")
 
 
 class TestGF256Matmul:
@@ -26,44 +31,167 @@ class TestGF256Matmul:
         assert np.array_equal(out, data)
 
 
+def _rows(layout: str, B: int = 2, S: int = 256):
+    """Segment ids and positions of packed rows.  ``packed``: pieces that
+    cross the 128-token blocks (one of a single token), then padding;
+    ``single``: one piece filling the row."""
+    seg = np.zeros((B, S), np.int32)
+    pos = np.zeros((B, S), np.int32)
+    pieces = {"packed": [[100, 60, 40, 20], [1, 130, 70]],
+              "single": [[S]] * B}[layout]
+    for b, lens in enumerate(pieces):
+        cur = 0
+        for sid, ln in enumerate(lens, start=1):
+            seg[b, cur:cur + ln] = sid
+            pos[b, cur:cur + ln] = np.arange(ln)
+            cur += ln
+    return jnp.asarray(seg), jnp.asarray(pos)
+
+
 class TestFlashAttention:
-    @pytest.mark.parametrize("B,S,H,KV,d", [
-        (1, 128, 2, 2, 64),    # MHA
-        (2, 256, 4, 2, 64),    # GQA 2:1
-        (1, 512, 8, 1, 128),   # MQA
-    ])
+    """The train step's kernel (interpret mode) against the model's own
+    attention, ``attention_naive``, at real positions: GQA 9/3, head_dim 64,
+    S 256 in blocks of 128, rows of packed segments."""
+
+    B, S, H, KV, D = 2, 256, 9, 3, 64
+    TOL = {jnp.float32: 1e-5, jnp.bfloat16: 1.5e-2}
+
+    @pytest.fixture(autouse=True)
+    def blocks_of_128(self, monkeypatch):
+        monkeypatch.setattr(flash, "BLOCK", 128)
+
+    def inputs(self, dtype, rng):
+        q = jnp.asarray(rng.normal(size=(self.B, self.S, self.H, self.D)), dtype)
+        k = jnp.asarray(rng.normal(size=(self.B, self.S, self.KV, self.D)), dtype)
+        v = jnp.asarray(rng.normal(size=(self.B, self.S, self.KV, self.D)), dtype)
+        return q, k, v
+
+    @staticmethod
+    def kernel(q, k, v, seg):
+        return flash.flash_attention(q, k, v, seg, interpret=True)
+
+    @staticmethod
+    def model(q, k, v, seg, pos):
+        from repro.models.attention import attention_naive
+        f32 = lambda x: x.astype(jnp.float32)
+        return attention_naive(f32(q), f32(k), f32(v), pos, pos, seg, seg)
+
+    @pytest.mark.parametrize("layout", ["packed", "single"])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    def test_matches_dense_oracle(self, B, S, H, KV, d, dtype, rng):
-        q = jnp.asarray(rng.normal(size=(B, S, H, d)), dtype)
-        k = jnp.asarray(rng.normal(size=(B, S, KV, d)), dtype)
-        v = jnp.asarray(rng.normal(size=(B, S, KV, d)), dtype)
-        out = flash_attention(q, k, v, bq=128, bk=64)
-        exp = ref.flash_attention_ref(q, k, v)
-        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(exp, np.float32), atol=tol)
+    def test_forward_matches_model_at_real_positions(self, layout, dtype, rng):
+        seg, pos = _rows(layout, self.B, self.S)
+        q, k, v = self.inputs(dtype, rng)
+        out = np.asarray(self.kernel(q, k, v, seg), np.float32)
+        want = np.asarray(self.model(q, k, v, seg, pos))
+        real = np.asarray(seg > 0)
+        np.testing.assert_allclose(out[real], want[real],
+                                   atol=self.TOL[dtype], rtol=self.TOL[dtype])
 
-    def test_non_causal(self, rng):
-        q = jnp.asarray(rng.normal(size=(1, 128, 2, 32)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(1, 128, 2, 32)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(1, 128, 2, 32)), jnp.float32)
-        out = flash_attention(q, k, v, causal=False, bq=64, bk=64)
-        exp = ref.flash_attention_ref(q, k, v, causal=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5)
+    @pytest.mark.parametrize("layout", ["packed", "single"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_grads_match_model(self, layout, dtype, rng):
+        """d/dq, d/dk, d/dv of a loss weighted by ``seg > 0``."""
+        seg, pos = _rows(layout, self.B, self.S)
+        q, k, v = self.inputs(dtype, rng)
+        w = (jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+             * (seg > 0)[:, :, None, None])
 
-    def test_matches_model_attention(self, rng):
-        """The kernel is a drop-in for models/attention.attention_chunked."""
-        from repro.models.attention import attention_chunked
-        B, S, H, d = 1, 256, 4, 64
-        q = jnp.asarray(rng.normal(size=(B, S, H, d)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, S, H, d)), jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, S, H, d)), jnp.float32)
-        pos = jnp.broadcast_to(jnp.arange(S), (B, S)).astype(jnp.int32)
-        seg = jnp.ones((B, S), jnp.int32)
-        out_model = attention_chunked(q, k, v, pos, pos, seg, seg, chunk=64)
-        out_kernel = flash_attention(q, k, v, bq=64, bk=64)
-        np.testing.assert_allclose(np.asarray(out_model), np.asarray(out_kernel),
-                                   atol=3e-5)
+        def grads(attend):
+            loss = lambda q, k, v: (attend(q, k, v).astype(jnp.float32)
+                                    * w).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        got = grads(lambda q, k, v: self.kernel(q, k, v, seg))
+        want = grads(lambda q, k, v: self.model(q, k, v, seg, pos))
+        for name, g, e in zip("qkv", got, want):
+            g, e = np.asarray(g, np.float32), np.asarray(e, np.float32)
+            err = np.linalg.norm(g - e) / np.linalg.norm(e)
+            assert err < self.TOL[dtype], (name, err)
+
+    def test_never_attends_across_segments(self, rng):
+        """Changing one segment's keys and values leaves every other
+        segment's outputs bit for bit as they were."""
+        seg, _ = _rows("packed", self.B, self.S)
+        q, k, v = self.inputs(jnp.float32, rng)
+        inside = (seg == 2)[:, :, None, None]
+        out = np.asarray(self.kernel(q, k, v, seg))
+        moved = np.asarray(self.kernel(q, jnp.where(inside, -k, k),
+                                       jnp.where(inside, 3 * v, v), seg))
+        others = np.asarray(seg != 2)
+        assert np.array_equal(out[others], moved[others])
+        assert not np.array_equal(out[~others], moved[~others])
+
+
+class TestAttentionDispatch:
+    """``models.model._attention_path``: the kernel on a TPU only; the jnp
+    paths, exactly as before, on the CPU and on the dry-run cost path."""
+
+    @staticmethod
+    def cfg(**kw):
+        from repro.configs import get_config
+        return get_config("smollm-135m").replace(**kw)
+
+    @pytest.mark.parametrize("backend,devices,kw,window,S,path", [
+        ("tpu", 1, {}, None, 2048, "kernel"),
+        ("tpu", 1, {}, None, 1024, "kernel"),
+        ("cpu", 1, {}, None, 2048, "chunked"),
+        ("tpu", 4, {}, None, 2048, "chunked"),
+        ("tpu", 1, {}, 512, 2048, "local"),
+        ("tpu", 1, {}, 4096, 2048, "chunked"),
+        ("tpu", 1, {}, None, 2000, "chunked"),
+        ("tpu", 1, {"unroll_scans": True}, None, 2048, "chunked"),
+        ("tpu", 1, {"head_dim": 128}, None, 2048, "chunked"),
+        ("tpu", 1, {"attn_impl": "naive"}, None, 2048, "naive"),
+    ])
+    def test_path(self, monkeypatch, backend, devices, kw, window, S, path):
+        from repro.models import model
+        monkeypatch.setattr(model.jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(model.jax, "device_count", lambda: devices)
+        assert model._attention_path(self.cfg(**kw), window, S) == path
+
+    @staticmethod
+    def run_forward(cfg, seg, pos):
+        from repro.models.model import forward, model_defs
+        from repro.models.params import init_params
+        params = init_params(jax.random.PRNGKey(0), model_defs(cfg))
+        toks = (jnp.arange(seg.size).reshape(seg.shape) % 97 + 1) * (seg > 0)
+        return forward(cfg, params, {"tokens": toks.astype(jnp.int32),
+                                     "segments": seg, "positions": pos})[0]
+
+    def test_cpu_model_runs_the_jnp_paths(self):
+        from repro.models.model import ATTENTION_PATHS
+        seg, pos = _rows("packed")
+        before = ATTENTION_PATHS.copy()
+        for chunk in (64, 1024):   # S above, then at most, the chunk
+            cfg = self.cfg(num_layers=2, d_model=64, d_ff=128, vocab_size=128,
+                           attn_chunk=chunk, dtype="float32",
+                           param_dtype="float32")
+            self.run_forward(cfg, seg, pos)
+        assert ATTENTION_PATHS - before == {"chunked": 1, "naive": 1}
+
+    def test_tpu_dispatch_runs_the_kernel_like_the_jnp_path(self, monkeypatch):
+        """With the backend seen as a TPU, a model of the fed cell's heads
+        lowers its attention to the kernel (interpreted here), counted once
+        per trace of the scanned layer; its hidden states at real positions
+        match the CPU's jnp path."""
+        from functools import partial
+
+        from repro.models import model
+        seg, pos = _rows("packed")
+        cfg = self.cfg(num_layers=2, d_model=64, d_ff=128, vocab_size=128,
+                       attn_chunk=64, dtype="float32", param_dtype="float32")
+        want = self.run_forward(cfg, seg, pos)
+        monkeypatch.setattr(flash, "BLOCK", 128)
+        monkeypatch.setattr(model.kernel_ops, "flash_attention",
+                            partial(flash.flash_attention, interpret=True))
+        monkeypatch.setattr(model.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(model.jax, "device_count", lambda: 1)
+        before = model.ATTENTION_PATHS.copy()
+        got = self.run_forward(cfg, seg, pos)
+        assert model.ATTENTION_PATHS - before == {"kernel": 1}
+        real = np.asarray(seg > 0)
+        np.testing.assert_allclose(np.asarray(got)[real],
+                                   np.asarray(want)[real], atol=1e-4, rtol=1e-4)
 
 
 class TestPackTokens:

@@ -64,10 +64,18 @@ def test_gf256_matmul_compiles_rs_10_3(shape):
     assert "tpu_custom_call" in text
 
 
-def test_flash_attention_compiles_at_smollm_widths(shape):
+@pytest.mark.parametrize("pass_", ["forward", "grad"])
+def test_flash_attention_compiles_at_the_fed_cell_shape(shape, pass_):
+    """The train step's attention at the fed cell's shape: 8 rows of 2048
+    tokens, SmolLM-135M's 9 query and 3 key/value heads of 64."""
     from repro.configs import get_config
     from repro.kernels.flash_attention import flash_attention
     cfg = get_config("smollm-135m")
-    q = shape((1, SEQ, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
-    kv = shape((1, SEQ, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
-    assert "tpu_custom_call" in compiled_text(flash_attention, q, kv, kv)
+    q = shape((8, SEQ, cfg.n_heads, cfg.head_dim), jnp.bfloat16)
+    kv = shape((8, SEQ, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
+    seg = shape((8, SEQ), jnp.int32)
+    fn = flash_attention
+    if pass_ == "grad":
+        fn = jax.grad(lambda q, k, v, s: flash_attention(q, k, v, s).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+    assert "tpu_custom_call" in compiled_text(fn, q, kv, kv, seg)
