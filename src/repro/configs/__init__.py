@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs as selectable configs.
+"""Architecture registry: the registered archs as selectable configs.
 
 ``get_config(name)`` returns the FULL paper-table config (exercised only via
 the AOT dry-run); ``get_smoke(name)`` returns the reduced same-family config
@@ -11,7 +11,8 @@ from typing import Dict, List
 from ..models.config import ModelConfig
 from . import (gemma_7b, glm4_9b, internlm2_20b, kimi_k2_1t_a32b,
                llama_3_2_vision_90b, mamba2_2_7b, mixtral_8x22b,
-               musicgen_medium, recurrentgemma_2b, smollm_135m)
+               moonlight_16b_a3b, musicgen_medium, recurrentgemma_2b,
+               smollm_135m)
 from .shapes import (LONG_CONTEXT_OK, SHAPES, ShapeSpec, cache_len_for,
                      input_specs, shape_applicable)
 
@@ -24,6 +25,7 @@ _MODULES = {
     "smollm-135m": smollm_135m,
     "recurrentgemma-2b": recurrentgemma_2b,
     "kimi-k2-1t-a32b": kimi_k2_1t_a32b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     "mixtral-8x22b": mixtral_8x22b,
     "musicgen-medium": musicgen_medium,
 }

@@ -25,12 +25,14 @@ exact arithmetic.  No query attends across segments.
 
 Precision.  q, k and v keep their dtype (bf16 in training).  Logits, the
 running max and sum, and every accumulator are float32.  The scale
-``head_dim ** -0.5`` is folded into q; the head sizes the kernel takes
-(``HEAD_DIMS``) make it a power of two, so the fold rounds nothing.  The
-forward's value product takes the probabilities in float32; the backward
-rounds the probabilities and their gradient to the input dtype only as
-operands of its dv, dq and dk products, where the jnp path's backward
-rounds them too.
+``head_dim ** -0.5`` is folded into q in float32, then q is rounded once to
+k's dtype: a bf16 q at a power-of-two scale (head 64 or 256) keeps its
+values exactly, and a caller may pass q in float32 (latent attention, qk
+head 192) so that the scale enters before q's one rounding.  v may have a
+head size of its own (128 against qk 192).  The forward's value product
+takes the probabilities in float32; the backward rounds the probabilities
+and their gradient to the input dtype only as operands of its dv, dq and dk
+products, where the jnp path's backward rounds them too.
 
 GQA: query head h reads key/value head ``h // (H // KV)`` through the
 kernels' index maps; repeated K/V are never materialised.
@@ -43,8 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-# head sizes whose scale head_dim ** -0.5 is a power of two
-HEAD_DIMS = (64, 256)
+# the (q and k, v) head sizes the kernel takes
+HEAD_DIMS = ((64, 64), (256, 256), (192, 128))
 # q and kv block of every kernel, forward and backward (at most the sequence)
 BLOCK = 512
 # splash blocks must tile the MXU's lanes
@@ -55,10 +57,12 @@ def block_size(seq_len: int) -> int:
     return min(seq_len, BLOCK)
 
 
-def supports(seq_len: int, head_dim: int) -> bool:
-    """Whether the kernel takes a sequence of ``seq_len`` at ``head_dim``."""
+def supports(seq_len: int, head_dim: int, head_dim_v: int = 0) -> bool:
+    """Whether the kernel takes a sequence of ``seq_len`` at ``head_dim``
+    (q and k) and ``head_dim_v`` (v; 0: the same)."""
     b = block_size(seq_len)
-    return head_dim in HEAD_DIMS and b % _LANES == 0 and seq_len % b == 0
+    return ((head_dim, head_dim_v or head_dim) in HEAD_DIMS
+            and b % _LANES == 0 and seq_len % b == 0)
 
 
 @functools.lru_cache(maxsize=None)
@@ -75,14 +79,16 @@ def _kernel(seq_len: int, n_heads: int, b: int, interpret: bool):
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     seg: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """q (B, S, H, Dh), k/v (B, S, KV, Dh), seg (B, S) -> (B, S, H, Dh).
+    """q (B, S, H, Dh), k (B, S, KV, Dh), v (B, S, KV, Dv), seg (B, S) ->
+    (B, S, H, Dv).
 
     ``seg`` holds each token's segment id (0 = padding); see the module
-    docstring for the mask and what it means for padding queries."""
+    docstring for the mask and what it means for padding queries.  q may
+    be float32 (scaled before its one rounding to k's dtype)."""
     B, S, H, Dh = q.shape
-    assert supports(S, Dh), (S, Dh)
+    assert supports(S, Dh, v.shape[-1]), (S, Dh, v.shape[-1])
     kernel = _kernel(S, H, block_size(S), interpret)
-    q = q * jnp.asarray(Dh ** -0.5, q.dtype)
+    q = (q.astype(jnp.float32) * Dh ** -0.5).astype(k.dtype)
     heads_first = lambda x: x.transpose(0, 2, 1, 3)
     seg = seg.astype(jnp.int32)
     out = jax.vmap(lambda q, k, v, s: kernel(

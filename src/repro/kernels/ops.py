@@ -7,7 +7,8 @@ lost its chip must not pass in interpret mode.
 Where each kernel runs: the ingest plan's pack and erasure operators take
 ``pack_tokens`` and ``gf256_matmul`` when built with ``use_pallas``; the
 train step's self-attention takes ``flash_attention`` on a TPU wherever the
-kernel applies (``models.model._attention_path``).  The dry-run cost path
+kernel applies (``models.model._attention_path``); the dropless expert layer
+(``models.moe.moe_dropless``) takes ``grouped_matmul`` on every backend.  The dry-run cost path
 (``ModelConfig.unroll_scans``) stays pure XLA: Pallas custom calls report no
 FLOPs to XLA's ``cost_analysis``.
 """
@@ -19,6 +20,7 @@ import jax
 
 from .flash_attention import flash_attention as _flash
 from .gf256_matmul import gf256_matmul as _gf256
+from .grouped_matmul import grouped_matmul as _gmm
 from .pack_tokens import pack_tokens as _pack
 
 
@@ -53,6 +55,12 @@ def flash_attention(q, k, v, seg, *, interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
     return _flash(q, k, v, seg, interpret=interpret)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = None):
+    if interpret is None:
+        interpret = _default_interpret()
+    return _gmm(lhs, rhs, group_sizes, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("seq_len", "pad_id", "interpret"))

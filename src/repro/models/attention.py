@@ -7,6 +7,8 @@ kernel instead of ``attention_chunked`` (``models.model._attention_path``).
 
 Conventions:
   q: (B, Sq, H, Dh)   k/v: (B, Sk, KV, Dh)   H = KV * q_per_kv
+  (self-attention and cross-attention also take v of its own head size Dv,
+  as latent attention has: the output is then (B, Sq, H, Dv))
   q_pos/k_pos: global positions within the packed block (causality),
   q_seg/k_seg: segment ids (packing isolation; 0 = padding).
 """
@@ -56,7 +58,7 @@ def _sdpa(q, k, v, mask, softcap: float = 0.0) -> jax.Array:
     logits = jnp.where(mask[:, None, None, :, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, H, Dh)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention_naive(q, k, v, q_pos, k_pos, q_seg, k_seg, *,
@@ -111,14 +113,15 @@ def attention_chunked(q, k, v, q_pos, k_pos, q_seg, k_seg, *,
         acc = acc * alpha[..., None] + pv
         return (acc, m_cur, l_cur), None
 
-    acc0 = jnp.zeros((B, KV, G, Sq, Dh), jnp.float32)
+    Dv = v.shape[-1]
+    acc0 = jnp.zeros((B, KV, G, Sq, Dv), jnp.float32)
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
                                   jnp.arange(n_chunks, dtype=jnp.int32),
                                   unroll=n_chunks if unroll else 1)
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dh).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv).astype(q.dtype)
 
 
 def attention_local(q, k, v, q_pos, k_pos, q_seg, k_seg, *, window: int,
@@ -185,7 +188,7 @@ def attention_decode(q, k_cache, v_cache, cache_len, *, window: Optional[int] = 
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v_cache)
-    return out.reshape(B, 1, H, Dh)
+    return out.reshape(B, 1, H, v_cache.shape[-1])
 
 
 def attention_cross(q, k, v, q_seg, *, softcap: float = 0.0) -> jax.Array:

@@ -48,17 +48,61 @@ def _chunked_xent(cfg: ModelConfig, params: Dict[str, Any], hidden: jax.Array,
         nloss, ncount = carry
         return (nloss + nll.sum(), ncount + v.sum()), None
 
+    if cfg.remat_loss:
+        body = jax.checkpoint(body)
     (loss_sum, count), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
         (hc, lc, vc.astype(jnp.float32)), unroll=n if cfg.unroll_scans else 1)
     return loss_sum, count
 
 
+def _router_stats(router: Dict[str, Any]) -> Dict[str, Any]:
+    """Per-step routing counters over the MoE layers that report them
+    (dropless dispatch), and each MoE layer's expert loads in the params'
+    layout (``load_tree``: None at layers without a router)."""
+    flat = [st for section in router.values() for st in section if st]
+    out: Dict[str, Any] = {}
+    if any("computed" in st for st in flat):
+        out["moe_computed"] = sum(jnp.sum(st["computed"]) for st in flat)
+        out["moe_max_load"] = jnp.max(jnp.stack(
+            [jnp.max(st["max_held_load"]) for st in flat]))
+        out["moe_dropped"] = sum(jnp.sum(st["dropped"]) for st in flat)
+    if flat:
+        out["load_tree"] = {sec: [st.get("load") if st else None for st in lst]
+                            for sec, lst in router.items()}
+    return out
+
+
+def balance_bias(cfg: ModelConfig, new_params: Dict[str, Any],
+                 old_params: Dict[str, Any], loads: Dict[str, Any]
+                 ) -> Dict[str, Any]:
+    """The aux-loss-free balancing step (DeepSeek-V3 §2.1.2): each router
+    bias moves by ``bias_rate * sign(mean load - load_i)`` from its value
+    before the step, whatever the optimizer made of it (its gradient is
+    zero: the bias only chooses experts)."""
+    rate = cfg.moe.bias_rate
+    out = dict(new_params)
+    for sec, lst in loads.items():
+        layers = list(out[sec])
+        for i, load in enumerate(lst):
+            if load is None or "router_bias" not in old_params[sec][i]["mlp"]:
+                continue
+            b = old_params[sec][i]["mlp"]["router_bias"]
+            step = rate * jnp.sign(jnp.mean(load, axis=-1, keepdims=True) - load)
+            mlp_p = dict(layers[i]["mlp"], router_bias=(b + step).astype(b.dtype))
+            layers[i] = dict(layers[i], mlp=mlp_p)
+        out[sec] = layers
+    return out
+
+
 def loss_fn(cfg: ModelConfig, params: Dict[str, Any], batch: Dict[str, jax.Array],
-            *, loss_chunk: int = 1024, moe_aux_weight: float = 0.01,
+            *, loss_chunk: int = 1024,
             constrain=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mean next-token NLL over valid (segment>0) positions + MoE aux loss."""
-    hidden, moe_aux = forward(cfg, params, batch, constrain=constrain)
+    """Mean next-token NLL over valid (segment>0) positions + MoE aux loss
+    weighted by ``cfg.moe.aux_weight``."""
+    hidden, aux = forward(cfg, params, batch, constrain=constrain)
+    moe_aux = aux["moe_aux"]
+    moe_aux_weight = cfg.moe.aux_weight if cfg.moe is not None else 0.01
     labels = batch["labels"]
     valid = (batch["segments"] > 0) & (labels >= 0)
     loss_sum, count = _chunked_xent(cfg, params, hidden, labels,
@@ -66,7 +110,7 @@ def loss_fn(cfg: ModelConfig, params: Dict[str, Any], batch: Dict[str, jax.Array
     xent = loss_sum / jnp.maximum(count, 1.0)
     total = xent + moe_aux_weight * moe_aux
     return total, {"loss": total, "xent": xent, "moe_aux": moe_aux,
-                   "tokens": count}
+                   "tokens": count, **_router_stats(aux["router"])}
 
 
 def make_train_step(cfg: ModelConfig, *, loss_chunk: int = 1024,
@@ -118,8 +162,14 @@ def make_train_step(cfg: ModelConfig, *, loss_chunk: int = 1024,
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
             loss = loss / grad_accum
             metrics = jax.tree.map(lambda m: m[-1], ms)
+            if "load_tree" in ms:       # the bias follows the whole batch
+                metrics["load_tree"] = jax.tree.map(lambda m: m.sum(0),
+                                                    ms["load_tree"])
             metrics["loss"] = loss
+        loads = metrics.pop("load_tree", None)
         new_params, new_opt, opt_metrics = opt_update(grads, opt_state, params)
+        if loads is not None and cfg.moe is not None and cfg.moe.bias_rate:
+            new_params = balance_bias(cfg, new_params, params, loads)
         metrics.update(opt_metrics)
         return new_params, new_opt, metrics
 
