@@ -10,6 +10,12 @@ are named ``splash_mha_fwd_segmented_residuals``,
 ``splash_mha_dq_segmented_no_residuals`` and
 ``splash_mha_dkv_segmented_no_residuals``.
 
+Residuals.  The forward's output and log-sum-exp, all the backward kernels
+need of it, carry the ``checkpoint_name`` ``RESIDUALS``.  A remat policy
+that saves that name keeps them through a layer checkpoint, and the
+backward then runs no forward kernel of its own; under one that saves
+nothing, the backward recomputes them with the forward kernel.
+
 Mask.  Key j is visible to query i when both lie in the same segment and
 j <= i, both computed inside the kernel from the segment ids and the
 indices; KV blocks wholly above the diagonal are skipped.  The model's mask
@@ -51,6 +57,8 @@ HEAD_DIMS = ((64, 64), (256, 256), (192, 128))
 BLOCK = 512
 # splash blocks must tile the MXU's lanes
 _LANES = 128
+# the checkpoint name of the forward's output and log-sum-exp
+RESIDUALS = "attention_kernel_residuals"
 
 
 def block_size(seq_len: int) -> int:
@@ -73,8 +81,12 @@ def _kernel(seq_len: int, n_heads: int, b: int, interpret: bool):
         block_q=b, block_kv=b, block_kv_compute=b,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
         block_q_dq=b, block_kv_dq=b)
-    return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
-                                  q_seq_shards=1, interpret=interpret)
+    # the kernel holds its mask tables as arrays: make them concrete, so
+    # that the cache holds no tracer of the trace that first built it
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                      q_seq_shards=1, interpret=interpret,
+                                      residual_checkpoint_name=RESIDUALS)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -147,7 +147,7 @@ def main() -> int:
     from ..core import DataStore
     from ..data.feeder import BlockFeeder, ingest_corpus
     from ..data.generators import gen_token_documents
-    from ..models.model import ATTENTION_PATHS
+    from ..models.model import ATTENTION_PATHS, ATTENTION_RESIDUALS
     from ..models.params import abstract_params
     from ..training.checkpoint import CheckpointManager
     from .compile_cache import enable_compile_cache
@@ -197,13 +197,15 @@ def main() -> int:
     t0 = time.time()
     losses = []
     traced = Counter(ATTENTION_PATHS)
+    traced_residuals = Counter(ATTENTION_RESIDUALS)
     for i, raw in enumerate(feeder.batches(args.steps)):
         batch = trainer.put_batch(make_batch(raw, args.seq_len))
         params, opt_state, metrics = trainer.step(params, opt_state, batch)
         step = start + i + 1
         if i == 0:   # the first call traced the step
             print(f"[train] attention calls traced by path: "
-                  f"{dict(ATTENTION_PATHS - traced)}")
+                  f"{dict(ATTENTION_PATHS - traced)}, kernel residuals: "
+                  f"{dict(ATTENTION_RESIDUALS - traced_residuals)}")
         losses.append(float(metrics["loss"]))
         if step % args.log_every == 0:
             dt = (time.time() - t0) / (i + 1)

@@ -150,7 +150,10 @@ class ModelConfig:
 
     # distribution
     optimizer: str = "adamw"       # adamw | adafactor (1T-scale)
-    remat_policy: str = "save_layer_inputs"   # nothing | save_layer_inputs | dots
+    # layer checkpoint: nothing | save_layer_inputs | dots | dots_no_batch;
+    # save_layer_inputs keeps the scan carry's layer inputs and the attention
+    # kernel's output and log-sum-exp, and recomputes the rest of the layer
+    remat_policy: str = "save_layer_inputs"
     sharding_overrides: Dict[str, Any] = field(default_factory=dict, hash=False)
 
     # serving

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops as kernel_ops
+from ..kernels.flash_attention import RESIDUALS as FLASH_RESIDUALS
 from ..kernels.flash_attention import supports as flash_supports
 from . import attention as attn
 from .config import LEADING_MLP, ModelConfig
@@ -171,6 +172,17 @@ def _pin_w(constrain, name: str, w: jax.Array) -> jax.Array:
 #: ("kernel", "chunked", "naive", "local"); counted at trace time, so a
 #: scanned layer counts once per trace of the step
 ATTENTION_PATHS: Counter = Counter()
+#: kernel-path calls traced, by whether the layer checkpoint keeps the
+#: kernel's output and log-sum-exp ("saved") or the backward runs the
+#: forward kernel again ("recomputed"); counted at trace time
+ATTENTION_RESIDUALS: Counter = Counter()
+
+
+def _count_attention(cfg: ModelConfig, path: str) -> None:
+    ATTENTION_PATHS[path] += 1
+    if path == "kernel":
+        kept = cfg.remat_policy != "nothing"   # see ``_remat_policy``
+        ATTENTION_RESIDUALS["saved" if kept else "recomputed"] += 1
 
 
 def _attention_path(cfg: ModelConfig, window: Optional[int], S: int,
@@ -234,7 +246,7 @@ def _mla_attention(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     S = x.shape[1]
     q, k, v = _mla_qkv(cfg, p, x, pos)
     path = _attention_path(cfg, None, S, a.qk_head_dim, a.v_head_dim)
-    ATTENTION_PATHS[path] += 1
+    _count_attention(cfg, path)
     if path == "kernel":
         o = kernel_ops.flash_attention(q, k, v, seg)
     elif path == "chunked":
@@ -259,7 +271,7 @@ def _self_attention(cfg: ModelConfig, kind: str, p: Dict[str, jax.Array],
     k = attn.rope(k, pos, cfg.rope_theta)
     window = cfg.window if kind in ("swa", "local") else None
     path = _attention_path(cfg, window, S)
-    ATTENTION_PATHS[path] += 1
+    _count_attention(cfg, path)
     if path == "local":
         o = attn.attention_local(q, k, v, pos, pos, seg, seg, window=window)
     elif path == "kernel":
@@ -335,13 +347,19 @@ def apply_block(cfg: ModelConfig, kind: str, p: Dict[str, Any], x: jax.Array,
 
 
 def _remat_policy(cfg: ModelConfig):
+    """The layer checkpoint's policy.  Every policy but ``nothing`` keeps
+    the attention kernel's residuals (``FLASH_RESIDUALS``); a call on a jnp
+    path carries no such name and is recomputed as the policy says."""
     cp = jax.checkpoint_policies
-    return {
-        "nothing": cp.nothing_saveable,
+    if cfg.remat_policy == "nothing":
+        return cp.nothing_saveable
+    residuals = cp.save_only_these_names(FLASH_RESIDUALS)
+    if cfg.remat_policy == "save_layer_inputs":
+        return residuals
+    return cp.save_from_both_policies({
         "dots": cp.dots_saveable,
-        "save_layer_inputs": cp.nothing_saveable,
         "dots_no_batch": cp.dots_with_no_batch_dims_saveable,
-    }[cfg.remat_policy]
+    }[cfg.remat_policy], residuals)
 
 
 # =================================================================== forward

@@ -194,6 +194,115 @@ class TestAttentionDispatch:
                                    np.asarray(want)[real], atol=1e-4, rtol=1e-4)
 
 
+def _pallas_calls(jaxpr, name: str) -> int:
+    """The ``pallas_call`` equations named ``name`` in ``jaxpr`` and in
+    every jaxpr nested in its equations."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call" and eqn.params["name"] == name
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    n += _pallas_calls(sub, name)
+    return n
+
+
+class TestAttentionResiduals:
+    """The layer checkpoint keeps the kernel's output and log-sum-exp
+    (``flash.RESIDUALS``) under every policy but ``nothing``: the
+    backward then runs no forward kernel of its own, and its gradients are
+    those of the recomputation, bit for bit."""
+
+    B, S, H = 2, 256, 4
+    FWD = "splash_mha_fwd_segmented_residuals"
+
+    @pytest.fixture(autouse=True)
+    def blocks_of_128(self, monkeypatch):
+        monkeypatch.setattr(flash, "BLOCK", 128)
+
+    @staticmethod
+    def cfg(policy):
+        from repro.configs import get_config
+        return get_config("smollm-135m").replace(remat_policy=policy)
+
+    def grad(self, policy, q, k, v, seg):
+        """d/dq, d/dk, d/dv of a loss weighted by ``seg > 0``, with the
+        kernel under a layer checkpoint of ``policy``."""
+        from repro.models.model import _remat_policy
+        w = jnp.asarray(np.random.default_rng(1).normal(size=q.shape[:3]
+                                                        + v.shape[3:]),
+                        jnp.float32) * (seg > 0)[:, :, None, None]
+        layer = jax.checkpoint(
+            lambda q, k, v: (flash.flash_attention(q, k, v, seg,
+                                                   interpret=True)
+                             .astype(jnp.float32) * w).sum(),
+            policy=_remat_policy(self.cfg(policy)), prevent_cse=False)
+        return jax.grad(layer, argnums=(0, 1, 2))
+
+    def inputs(self, dh, dv, kv, rng):
+        """Two segments a row; q in float32 for latent attention, as the
+        model passes it, else bf16."""
+        seg = jnp.asarray(np.repeat([[1, 2]], self.B, 0).repeat(self.S // 2, 1),
+                          jnp.int32)
+        q = jnp.asarray(rng.normal(size=(self.B, self.S, self.H, dh)),
+                        jnp.float32 if dh != dv else jnp.bfloat16)
+        k = jnp.asarray(rng.normal(size=(self.B, self.S, kv, dh)), jnp.bfloat16)
+        v = jnp.asarray(rng.normal(size=(self.B, self.S, kv, dv)), jnp.bfloat16)
+        return q, k, v, seg
+
+    def forward_kernels(self, policy, q, k, v, seg) -> int:
+        jaxpr = jax.make_jaxpr(self.grad(policy, q, k, v, seg))(q, k, v)
+        return _pallas_calls(jaxpr.jaxpr, self.FWD)
+
+    @pytest.mark.parametrize("dh,dv,kv", [(64, 64, 2), (192, 128, 4)])
+    def test_backward_runs_no_forward_kernel(self, dh, dv, kv, rng):
+        """GQA at head 64, and latent attention's qk 192 / v 128."""
+        q, k, v, seg = self.inputs(dh, dv, kv, rng)
+        assert self.forward_kernels("save_layer_inputs", q, k, v, seg) == 1
+        assert self.forward_kernels("nothing", q, k, v, seg) == 2
+        saved = self.grad("save_layer_inputs", q, k, v, seg)(q, k, v)
+        again = self.grad("nothing", q, k, v, seg)(q, k, v)
+        for name, g, e in zip("qkv", saved, again):
+            assert g.dtype == e.dtype, name
+            assert np.array_equal(np.asarray(g), np.asarray(e)), name
+
+    @pytest.mark.parametrize("policy", ["dots", "dots_no_batch"])
+    def test_dot_policies_keep_the_residuals(self, policy, rng):
+        q, k, v, seg = self.inputs(64, 64, 2, rng)
+        assert self.forward_kernels(policy, q, k, v, seg) == 1
+
+    def test_nothing_saves_nothing(self):
+        from repro.models.model import _remat_policy
+        assert (_remat_policy(self.cfg("nothing"))
+                is jax.checkpoint_policies.nothing_saveable)
+
+    @pytest.mark.parametrize("policy,backend,counted", [
+        ("save_layer_inputs", "tpu", {"saved": 1}),
+        ("nothing", "tpu", {"recomputed": 1}),
+        ("save_layer_inputs", "cpu", {}),
+    ])
+    def test_counter(self, monkeypatch, policy, backend, counted):
+        """``ATTENTION_RESIDUALS`` counts each kernel-path call traced (the
+        scanned layer once) by the policy in force; the chunked path, none."""
+        from repro.models import model
+        from repro.models.params import init_params
+        monkeypatch.setattr(model.jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(model.jax, "device_count", lambda: 1)
+        cfg = self.cfg(policy).replace(num_layers=2, d_model=64, d_ff=128,
+                                       vocab_size=128, attn_chunk=64)
+        seg, pos = _rows("packed")
+        params = init_params(jax.random.PRNGKey(0), model.model_defs(cfg))
+        batch = {"tokens": jnp.ones(seg.shape, jnp.int32), "segments": seg,
+                 "positions": pos}
+        paths = model.ATTENTION_PATHS.copy()
+        before = model.ATTENTION_RESIDUALS.copy()
+        jax.eval_shape(lambda p: model.forward(cfg, p, batch)[0], params)
+        assert model.ATTENTION_RESIDUALS - before == counted
+        assert sum((model.ATTENTION_PATHS - paths).values()) == 1
+
+
 class TestPackTokens:
     @pytest.mark.parametrize("seq_len", [64, 128, 1024])
     def test_matches_oracle(self, seq_len, rng):
