@@ -11,7 +11,7 @@ import time
 
 
 MODULES = ["cleaning", "sampling", "layouts", "storage", "cooking",
-           "access", "recovery", "streaming", "roofline"]
+           "access", "recovery", "streaming"]
 
 
 def main() -> int:

@@ -8,9 +8,7 @@ Where each kernel runs: the ingest plan's pack and erasure operators take
 ``pack_tokens`` and ``gf256_matmul`` when built with ``use_pallas``; the
 train step's self-attention takes ``flash_attention`` on a TPU wherever the
 kernel applies (``models.model._attention_path``); the dropless expert layer
-(``models.moe.moe_dropless``) takes ``grouped_matmul`` on every backend.  The dry-run cost path
-(``ModelConfig.unroll_scans``) stays pure XLA: Pallas custom calls report no
-FLOPs to XLA's ``cost_analysis``.
+(``models.moe.moe_dropless``) takes ``grouped_matmul`` on every backend.
 """
 from __future__ import annotations
 

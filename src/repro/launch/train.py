@@ -100,7 +100,7 @@ def make_trainer(cfg, mesh, *, global_batch: int, seq_len: int, lr: float,
     from .mesh import (input_shardings, make_constrain, mesh_axis_sizes,
                        sharding_rules)
 
-    rules = sharding_rules(cfg, mesh, global_batch=global_batch)
+    rules = sharding_rules(cfg, mesh)
     sizes = mesh_axis_sizes(mesh)
     named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                    is_leaf=lambda x: isinstance(x, P))

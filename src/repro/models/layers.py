@@ -1,10 +1,10 @@
-"""Shared layers: RMSNorm, dense MLPs (SwiGLU/GeGLU/GELU), embeddings.
+"""Shared layers: RMSNorm, the dense SwiGLU MLP, embeddings.
 
 Pure-functional: every layer is ``fn(params_subtree, x, cfg) -> y`` with
 parameter *definitions* provided by matching ``*_defs`` functions so model.py
 can build the full ParamDef tree (shapes + logical sharding axes) in one place.
 
-Logical axis names (mapped to mesh axes by per-arch sharding rules):
+Logical axis names (mapped to mesh axes by the sharding rules):
   "embed"   — d_model dim          (FSDP: sharded over the data axis)
   "ffn"     — feed-forward hidden  (TP: sharded over the model axis)
   "heads"   — attention head dim   (TP)
@@ -15,7 +15,7 @@ Logical axis names (mapped to mesh axes by per-arch sharding rules):
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -40,30 +40,19 @@ def rmsnorm(p: Dict[str, jax.Array], x: jax.Array, eps: float) -> jax.Array:
 
 # ------------------------------------------------------------------- MLPs
 def mlp_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
+    """SwiGLU of width d_ff."""
     D, F = cfg.d_model, cfg.d_ff
     dt = jnp.dtype(cfg.param_dtype)
-    if cfg.mlp_kind in ("swiglu", "geglu"):
-        return {
-            "wi_gate": ParamDef((D, F), ("embed", "ffn"), dt),
-            "wi_up": ParamDef((D, F), ("embed", "ffn"), dt),
-            "wo": ParamDef((F, D), ("ffn", "embed"), dt, "scaled"),
-        }
-    if cfg.mlp_kind == "gelu":
-        return {
-            "wi": ParamDef((D, F), ("embed", "ffn"), dt),
-            "wo": ParamDef((F, D), ("ffn", "embed"), dt, "scaled"),
-        }
-    raise ValueError(f"mlp_defs: unsupported {cfg.mlp_kind!r}")
+    return {
+        "wi_gate": ParamDef((D, F), ("embed", "ffn"), dt),
+        "wi_up": ParamDef((D, F), ("embed", "ffn"), dt),
+        "wo": ParamDef((F, D), ("ffn", "embed"), dt, "scaled"),
+    }
 
 
-def mlp(p: Dict[str, jax.Array], x: jax.Array, kind: str) -> jax.Array:
-    if kind in ("swiglu", "geglu"):
-        act = jax.nn.silu if kind == "swiglu" else lambda v: jax.nn.gelu(v, approximate=True)
-        g = act(x @ p["wi_gate"])
-        return (g * (x @ p["wi_up"])) @ p["wo"]
-    if kind == "gelu":
-        return jax.nn.gelu(x @ p["wi"], approximate=True) @ p["wo"]
-    raise ValueError(f"mlp: unsupported {kind!r}")
+def mlp(p: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
+    g = jax.nn.silu(x @ p["wi_gate"])
+    return (g * (x @ p["wi_up"])) @ p["wo"]
 
 
 # ------------------------------------------------------------- embeddings
@@ -76,16 +65,10 @@ def embedding_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
 
 
 def embed(p: Dict[str, jax.Array], tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
-    x = jnp.take(p["embedding"], tokens, axis=0).astype(cfg.activation_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    return x
+    return jnp.take(p["embedding"], tokens, axis=0).astype(cfg.activation_dtype)
 
 
 def unembed(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Final projection to logits (fp32) with optional soft-capping."""
+    """Final projection to logits (fp32)."""
     w = p["embedding"].T if cfg.tied_embeddings else p["unembed"]
-    logits = (x @ w.astype(x.dtype)).astype(jnp.float32)
-    if cfg.logit_softcap > 0.0:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits
+    return (x @ w.astype(x.dtype)).astype(jnp.float32)

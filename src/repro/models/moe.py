@@ -238,14 +238,10 @@ def _moe_capacity(p: Dict[str, jax.Array], x: jax.Array, cfg: ModelConfig,
 
     # ---- dispatch -> expert MLP -> combine
     pin = constrain if constrain is not None else (lambda name, v: v)
-    wi_gate = pin("w_moe", p["wi_gate"])   # gathered-over-data, EP over model
-    wi_up = pin("w_moe", p["wi_up"])
-    wo = pin("w_moe_out", p["wo"])
     expert_in = pin("moe_ecd", jnp.einsum("ngec,ngd->necd", dispatch, xg))
-    act = jax.nn.silu if cfg.mlp_kind != "geglu" else jax.nn.gelu
-    h = act(jnp.einsum("necd,edf->necf", expert_in, wi_gate))
-    h = h * jnp.einsum("necd,edf->necf", expert_in, wi_up)
-    expert_out = pin("moe_ecd", jnp.einsum("necf,efd->necd", h, wo))  # (n,E,C,D)
+    h = jax.nn.silu(jnp.einsum("necd,edf->necf", expert_in, p["wi_gate"]))
+    h = h * jnp.einsum("necd,edf->necf", expert_in, p["wi_up"])
+    expert_out = pin("moe_ecd", jnp.einsum("necf,efd->necd", h, p["wo"]))  # (n,E,C,D)
     out = jnp.einsum("ngec,necd->ngd", combine.astype(x.dtype), expert_out)
 
     if m.num_shared_experts:

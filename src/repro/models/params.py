@@ -2,9 +2,10 @@
 logical sharding axes.
 
 A ``ParamDef`` records (shape, dtype, logical axes, initializer).  From a tree
-of defs we derive (a) initialized arrays, (b) ``jax.ShapeDtypeStruct``s for the
-AOT dry-run, and (c) ``PartitionSpec``s by mapping logical axis names through
-per-arch sharding rules (MaxText-style; DESIGN.md §5).
+of defs we derive (a) initialized arrays, (b) ``jax.ShapeDtypeStruct``s for
+checkpoint restore and the benchmark's shape check, and (c)
+``PartitionSpec``s by mapping logical axis names through the sharding rules
+(MaxText-style; DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ class ParamDef:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]      # logical axis per dim (None = replicated)
     dtype: Any = jnp.bfloat16
-    init: str = "normal"                 # normal | zeros | ones | scaled | a_param
+    init: str = "normal"                 # normal | zeros | scaled
     init_scale: float = 1.0
 
     def __post_init__(self):
@@ -43,12 +44,6 @@ def tree_map_defs(fn: Callable[[ParamDef], Any], tree: ParamTree) -> Any:
 def init_param(key: jax.Array, d: ParamDef) -> jax.Array:
     if d.init == "zeros":
         return jnp.zeros(d.shape, d.dtype)
-    if d.init == "ones":
-        return jnp.ones(d.shape, d.dtype)
-    if d.init == "a_param":
-        # RG-LRU decay parameterization: softplus-inv of decays in (0.9, 0.999)
-        u = jax.random.uniform(key, d.shape, jnp.float32, 0.9, 0.999)
-        return jnp.log(jnp.expm1(-jnp.log(u) * 8.0)).astype(d.dtype)
     fan_in = d.shape[0] if len(d.shape) >= 2 else max(1, d.shape[-1])
     if d.init == "scaled":
         std = d.init_scale / np.sqrt(fan_in)

@@ -3,9 +3,9 @@
 State trees mirror the parameter tree, so pjit shards optimizer state with
 the same PartitionSpecs as the parameters (via ``opt_state_specs``).
 
-Adafactor (factored second moments) is what makes kimi-k2 (1 T params)
-trainable on a 256-chip pod: AdamW fp32 state would need ~8 TB; Adafactor's
-row/col factors are ~(rows+cols)/(rows·cols) of that.
+Adafactor keeps factored second moments: row and column factors,
+~(rows+cols)/(rows·cols) of AdamW's float32 state.  No registered config
+selects it.
 """
 from __future__ import annotations
 
@@ -145,7 +145,8 @@ def make_optimizer(name: str, **kw: Any):
 
 
 def opt_state_defs(name: str, param_defs: Any, min_dim: int = 128) -> Any:
-    """ParamDef tree for the optimizer state (for AOT dry-run + sharding)."""
+    """ParamDef tree for the optimizer state (its shardings and checkpoint
+    restore)."""
     if name == "adamw":
         f32 = lambda d: ParamDef(d.shape, d.axes, jnp.float32, "zeros")
         return {"mu": tree_map_defs(f32, param_defs),

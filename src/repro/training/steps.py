@@ -1,4 +1,4 @@
-"""Train / serve step builders: loss, grads, optimizer update, metrics.
+"""Train step builder: loss, grads, optimizer update, metrics.
 
 ``make_train_step(cfg)`` returns a pure function
     (params, opt_state, batch, rng) -> (params, opt_state, metrics)
@@ -7,7 +7,7 @@ suitable for ``jax.jit`` with in/out shardings from the launch layer.
 The cross-entropy is computed in sequence chunks (``loss_chunk``) so the
 (B, S, V) logits tensor never materializes at once — with V up to 256 k this
 is the difference between fitting and OOM on a 16 GB chip.  FLOPs are
-unchanged (same matmuls, scanned), so the roofline's compute term is honest.
+unchanged (same matmuls, scanned).
 """
 from __future__ import annotations
 
@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from ..models.layers import unembed
-from ..models.model import decode_step, forward, prefill
+from ..models.model import forward
 from .optim import make_optimizer
 
 
@@ -52,7 +52,7 @@ def _chunked_xent(cfg: ModelConfig, params: Dict[str, Any], hidden: jax.Array,
         body = jax.checkpoint(body)
     (loss_sum, count), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (hc, lc, vc.astype(jnp.float32)), unroll=n if cfg.unroll_scans else 1)
+        (hc, lc, vc.astype(jnp.float32)))
     return loss_sum, count
 
 
@@ -174,23 +174,3 @@ def make_train_step(cfg: ModelConfig, *, loss_chunk: int = 1024,
         return new_params, new_opt, metrics
 
     return train_step
-
-
-def make_serve_step(cfg: ModelConfig, constrain=None) -> Callable:
-    """One-token decode step: (params, cache, tokens (B,1), pos) ->
-    (next_token (B,1), logits, cache)."""
-
-    def serve_step(params, cache, tokens, pos):
-        logits, new_cache = decode_step(cfg, params, cache, tokens, pos,
-                                        constrain=constrain)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)[:, None]
-        return nxt, logits, new_cache
-
-    return serve_step
-
-
-def make_prefill_step(cfg: ModelConfig, max_len: int, constrain=None) -> Callable:
-    def prefill_step(params, batch):
-        return prefill(cfg, params, batch, max_len, constrain=constrain)
-
-    return prefill_step

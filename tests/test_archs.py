@@ -1,42 +1,33 @@
-"""Per-arch smoke tests: every assigned architecture instantiates a REDUCED
-same-family config and runs one forward + one train step on CPU, asserting
-output shapes and finiteness.  Full configs are exercised by the dry-run only."""
+"""Per-arch smoke tests: every registered architecture instantiates its
+REDUCED config of the same blocks and runs one forward + one train step on
+CPU, asserting output shapes and finiteness; the published configs are
+checked by their analytic sizes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_config, get_smoke
-from repro.models.model import cache_defs, decode_step, forward, model_defs, prefill
-from repro.models.params import abstract_params, init_params
+from repro.models.config import ModelConfig
+from repro.models.model import forward, model_defs
+from repro.models.params import init_params
 from repro.training.optim import make_optimizer
 from repro.training.steps import make_train_step
 
 B, S = 2, 64
 
-# tier-1 compiles one representative arch; the full sweep is the slow tier
-# (each arch pays a multi-second JAX compile on CPU)
-FAST_ARCHS = ("smollm-135m",)
-ARCH_PARAMS = [a if a in FAST_ARCHS else pytest.param(a, marks=pytest.mark.slow)
-               for a in ARCHS]
-
 
 def make_batch(cfg, rng):
     toks = rng.integers(1, cfg.vocab_size, (B, S)).astype(np.int32)
-    batch = {
+    return {
         "tokens": jnp.asarray(toks),
         "labels": jnp.asarray(np.roll(toks, -1, axis=1)),
         "segments": jnp.ones((B, S), jnp.int32),
         "positions": jnp.broadcast_to(jnp.arange(S), (B, S)).astype(jnp.int32),
     }
-    if "cross" in cfg.pattern + cfg.remainder:
-        batch["encoder_embeds"] = jnp.asarray(
-            rng.normal(size=(B, cfg.cross_attn_kv_len, cfg.d_model)),
-            cfg.activation_dtype)
-    return batch
 
 
-@pytest.mark.parametrize("arch", ARCH_PARAMS)
+@pytest.mark.parametrize("arch", ARCHS)
 class TestArchSmoke:
     def test_forward_and_train_step(self, arch, rng):
         cfg = get_smoke(arch)
@@ -56,43 +47,31 @@ class TestArchSmoke:
             jax.tree.map(lambda x, y: bool(jnp.any(x != y)), params, p2))
         assert moved
 
-    def test_decode_matches_forward(self, arch, rng):
-        """Prefill+decode logits == full-forward logits (cache correctness)."""
-        cfg = get_smoke(arch)
-        if cfg.param_dtype != "float32":
-            cfg = cfg.replace(dtype="float32", param_dtype="float32")
-        params = init_params(jax.random.PRNGKey(0), model_defs(cfg))
-        batch = make_batch(cfg, rng)
-        from repro.models.layers import unembed
-        h, _ = forward(cfg, params, batch)
-        full_logits = unembed(params["embed"], h[:, -1:], cfg)
 
-        pre = {k: (v[:, :S - 1] if v.shape[:2] == (B, S) else v)
-               for k, v in batch.items()}
-        _, cache = prefill(cfg, params, pre, max_len=S + 8)
-        dec_logits, _ = decode_step(cfg, params, cache,
-                                    batch["tokens"][:, S - 1:S],
-                                    jnp.asarray(S - 1, jnp.int32))
-        np.testing.assert_allclose(np.asarray(full_logits, np.float32),
-                                   np.asarray(dec_logits, np.float32),
-                                   atol=5e-2, rtol=1e-3)
+@pytest.mark.parametrize("field,kind", [
+    ("pattern", "swa"), ("pattern", "local"), ("pattern", "cross"),
+    ("pattern", "ssd"), ("pattern", "rec"),
+    ("mlp_kind", "geglu"), ("mlp_kind", "gelu"), ("mlp_kind", "none"),
+])
+def test_unknown_block_and_mlp_kinds_are_refused(field, kind):
+    """The decoder has one block kind and two MLP kinds: any other is an
+    error when the config is made, not deep inside tracing."""
+    value = (kind,) if field == "pattern" else kind
+    with pytest.raises(ValueError, match=f"unknown .* kind {kind!r}"):
+        ModelConfig(**{field: value})
 
 
 def test_full_configs_match_published_sizes():
     """Analytic param counts stay within 10% of the published model sizes."""
-    expected = {
-        "mamba2-2.7b": 2.7e9, "llama-3.2-vision-90b": 88e9, "gemma-7b": 8.5e9,
-        "glm4-9b": 9.4e9, "internlm2-20b": 20e9, "smollm-135m": 135e6,
-        "recurrentgemma-2b": 2.7e9, "kimi-k2-1t-a32b": 1.04e12,
-        "mixtral-8x22b": 141e9, "musicgen-medium": 1.5e9,
-    }
+    expected = {"smollm-135m": 135e6, "moonlight-16b-a3b": 16e9}
     for arch, n in expected.items():
         got = get_config(arch).param_count()
         assert abs(got - n) / n < 0.12, f"{arch}: {got/1e9:.2f}B vs {n/1e9:.2f}B"
 
 
 def test_moe_active_params():
-    kimi = get_config("kimi-k2-1t-a32b")
-    assert 30e9 < kimi.active_param_count() < 40e9
-    mixtral = get_config("mixtral-8x22b")
-    assert 35e9 < mixtral.active_param_count() < 45e9
+    """Moonlight-16B-A3B: 16 B parameters, 3 B of them active per token
+    (6 of 64 routed experts and the 2 shared ones)."""
+    moonlight = get_config("moonlight-16b-a3b")
+    assert 15.9e9 < moonlight.param_count() < 16.0e9
+    assert 2.9e9 < moonlight.active_param_count() < 3.0e9

@@ -124,30 +124,36 @@ class TestFlashAttention:
 
 class TestAttentionDispatch:
     """``models.model._attention_path``: the kernel on a TPU only; the jnp
-    paths, exactly as before, on the CPU and on the dry-run cost path."""
+    paths on the CPU and on a mesh."""
 
     @staticmethod
     def cfg(**kw):
         from repro.configs import get_config
         return get_config("smollm-135m").replace(**kw)
 
-    @pytest.mark.parametrize("backend,devices,kw,window,S,path", [
-        ("tpu", 1, {}, None, 2048, "kernel"),
-        ("tpu", 1, {}, None, 1024, "kernel"),
-        ("cpu", 1, {}, None, 2048, "chunked"),
-        ("tpu", 4, {}, None, 2048, "chunked"),
-        ("tpu", 1, {}, 512, 2048, "local"),
-        ("tpu", 1, {}, 4096, 2048, "chunked"),
-        ("tpu", 1, {}, None, 2000, "chunked"),
-        ("tpu", 1, {"unroll_scans": True}, None, 2048, "chunked"),
-        ("tpu", 1, {"head_dim": 128}, None, 2048, "chunked"),
-        ("tpu", 1, {"attn_impl": "naive"}, None, 2048, "naive"),
+    # heads: the (q/k, v) head sizes of the call, () for the config's own
+    @pytest.mark.parametrize("backend,devices,kw,heads,S,path", [
+        pytest.param("tpu", 1, {}, (), 2048, "kernel",
+                     id="tpu-1-kw0-None-2048-kernel"),
+        pytest.param("tpu", 1, {}, (), 1024, "kernel",
+                     id="tpu-1-kw1-None-1024-kernel"),
+        pytest.param("cpu", 1, {}, (), 2048, "chunked",
+                     id="cpu-1-kw2-None-2048-chunked"),
+        pytest.param("tpu", 4, {}, (), 2048, "chunked",
+                     id="tpu-4-kw3-None-2048-chunked"),
+        pytest.param("tpu", 1, {}, (), 2000, "chunked",
+                     id="tpu-1-kw6-None-2000-chunked"),
+        pytest.param("tpu", 1, {"head_dim": 128}, (), 2048, "chunked",
+                     id="tpu-1-kw8-None-2048-chunked"),
+        pytest.param("cpu", 1, {}, (), 1024, "naive", id="cpu-1-1024-naive"),
+        pytest.param("tpu", 1, {}, (192, 128), 8192, "kernel",
+                     id="tpu-1-mla-8192-kernel"),
     ])
-    def test_path(self, monkeypatch, backend, devices, kw, window, S, path):
+    def test_path(self, monkeypatch, backend, devices, kw, heads, S, path):
         from repro.models import model
         monkeypatch.setattr(model.jax, "default_backend", lambda: backend)
         monkeypatch.setattr(model.jax, "device_count", lambda: devices)
-        assert model._attention_path(self.cfg(**kw), window, S) == path
+        assert model._attention_path(self.cfg(**kw), S, *heads) == path
 
     @staticmethod
     def run_forward(cfg, seg, pos):

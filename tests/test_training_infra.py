@@ -1,4 +1,4 @@
-"""Optimizers, checkpointing (async + elastic), feeder, and dry-run helpers."""
+"""Optimizers, checkpointing (async + elastic), feeder, and sharding helpers."""
 import json
 import os
 
@@ -39,7 +39,7 @@ class TestOptimizers:
 
     def test_opt_state_defs_match_runtime_state(self):
         """ShapeDtypeStructs from opt_state_defs == actual optimizer state
-        (so dry-run shardings are valid for the real thing)."""
+        (so the trainer's state shardings fit the real state)."""
         from repro.models.params import ParamDef, abstract_params, init_params
         pdefs = {"w": ParamDef((256, 192), ("embed", "ffn"), jnp.float32),
                  "s": ParamDef((16,), (None,), jnp.float32)}
@@ -197,36 +197,8 @@ class TestFeeder:
         assert q.delivered() <= 3 + 8 + len(feeders)
 
 
-# --------------------------------------------------------- dry-run utilities
+# ------------------------------------------------------- sharding helpers
 class TestDryrunHelpers:
-    def test_collective_parser_ring_model(self):
-        from repro.launch.dryrun import parse_collectives
-        hlo = """
-  %ar = f32[16,1024]{1,0} all-reduce(f32[16,1024] %x), replica_groups=[16,16]<=[256]
-  %ag = bf16[8,4096]{1,0} all-gather(bf16[8,256] %y), replica_groups={{0,1,2,3}}
-  %t = (f32[4,4]{1,0}, f32[4,4]{1,0}) all-reduce(%a, %b), replica_groups=[16,16]<=[16,16]T(1,0)
-        """
-        out = parse_collectives(hlo)
-        assert out["by_kind_count"]["all-reduce"] == 2
-        assert out["by_kind_count"]["all-gather"] == 1
-        ar1 = 2 * (16 * 1024 * 4) * 15 / 16
-        ag = (8 * 4096 * 2) * 3 / 4
-        art = 2 * (2 * 4 * 4 * 4) * 15 / 16
-        assert abs(out["total_bytes"] - (ar1 + ag + art)) < 1
-
-    def test_extrapolation_is_linear(self):
-        from repro.launch.dryrun import _extrapolate
-        c1 = {"flops": 10.0, "bytes": 100.0, "bytes_raw": 200.0,
-              "coll": {"total_bytes": 6.0, "by_kind_bytes": {"all-reduce": 6.0},
-                       "by_kind_count": {"all-reduce": 2}}}
-        c2 = {"flops": 14.0, "bytes": 120.0, "bytes_raw": 260.0,
-              "coll": {"total_bytes": 8.0, "by_kind_bytes": {"all-reduce": 8.0},
-                       "by_kind_count": {"all-reduce": 3}}}
-        out = _extrapolate(c1, c2, 10)
-        assert out["flops"] == 10 + 4 * 9
-        assert out["coll"]["by_kind_bytes"]["all-reduce"] == 6 + 2 * 9
-        assert out["coll"]["by_kind_count"]["all-reduce"] == 2 + 1 * 9
-
     def test_sharding_rules_divisibility(self):
         """9 heads never shard 16 ways; vocab multiples of 256 do."""
         from repro.models.params import logical_to_spec
@@ -244,17 +216,6 @@ class TestDryrunHelpers:
         spec = logical_to_spec(("vocab", "embed"), rules, (49152, 576),
                                {"data": 4})
         assert spec == jax.sharding.PartitionSpec(None, "data")
-
-    def test_importing_dryrun_leaves_xla_flags_alone(self, monkeypatch):
-        import importlib
-        monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
-        import repro.launch.dryrun as dryrun
-        importlib.reload(dryrun)
-        assert os.environ["XLA_FLAGS"] == "--xla_dump_to=/dev/null"
-        dryrun.force_host_devices(8)
-        dryrun.force_host_devices(8)
-        assert os.environ["XLA_FLAGS"] == (
-            "--xla_dump_to=/dev/null --xla_force_host_platform_device_count=8")
 
 
 class TestChipPeaks:
