@@ -37,6 +37,7 @@ Dispatch (``MoEConfig.dispatch``):
 from __future__ import annotations
 
 from collections import Counter
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -138,22 +139,96 @@ def _seq_balance(idx: jax.Array, probs: jax.Array, valid: Optional[jax.Array],
     return jnp.mean(jnp.sum(f * P, axis=-1)), jnp.sum(chose, axis=(0, 1))
 
 
+#: the fast branch's rows over a layer's expected routed load: a constant,
+#: not an option (``moe_dropless``)
+HEADROOM = 2
+
+
+def _experts(rows: int, x: jax.Array, w: jax.Array, order: jax.Array,
+             slot: jax.Array, sizes: jax.Array, wi_gate: jax.Array,
+             wi_up: jax.Array, wo: jax.Array) -> jax.Array:
+    """The held experts' products over ``rows`` sorted rows, added back to
+    the tokens.  x (T, D); w (T, k) each choice's weight, 0 where its
+    expert is held elsewhere; order (T k,) the assignments sorted by held
+    expert; slot (T, k) each choice's row in that order, 0 where held
+    elsewhere; sizes (H,) the held experts' groups.  Returns (T, D)
+    float32, exact while ``rows`` is at least ``sum(sizes)``."""
+    T, k = w.shape
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    # rows past the groups are never visited by the kernels: every product's
+    # output is masked there before it meets another product, forward and
+    # backward (a select, so that whatever those rows hold stays out)
+    gmm = lambda a, wt: jnp.where(live, kernel_ops.grouped_matmul(a, wt, sizes), 0)
+    xs = jnp.where(live, x[order[:rows] // k], 0)
+    ys = gmm(jax.nn.silu(gmm(xs, wi_gate)) * gmm(xs, wi_up), wo)
+    # back to the tokens: each (token, choice) held here reads its row of
+    # the sorted products (a gather, whose backward writes each row once),
+    # one choice at a time into a float32 sum
+    slot = jnp.minimum(slot, rows - 1)
+    out = jnp.zeros((T, x.shape[1]), jnp.float32)
+    for j in range(k):
+        out = out + w[:, j:j + 1] * ys[slot[:, j]].astype(jnp.float32)
+    return out
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _bounded_experts(rows: int, full: int, x, w, order, slot, sizes,
+                     wi_gate, wi_up, wo) -> jax.Array:
+    """``_experts`` over ``rows`` rows where the routed assignments fit
+    them, else over ``full``.  Forward and backward each pick their branch
+    with a ``lax.cond`` and keep only the inputs, so no residual of the
+    full-size branch is carried (and zero-filled) on the fast one."""
+    return jax.lax.cond(jnp.sum(sizes) <= rows, partial(_experts, rows),
+                        partial(_experts, full), x, w, order, slot, sizes,
+                        wi_gate, wi_up, wo)
+
+
+def _bounded_fwd(rows, full, *args):
+    return _bounded_experts(rows, full, *args), args
+
+
+def _bounded_bwd(rows, full, args, g):
+    x, w, order, slot, sizes, *weights = args
+
+    def branch(n):
+        def grads(x, w, *weights):
+            f = lambda x, w, *ws: _experts(n, x, w, order, slot, sizes, *ws)
+            return jax.vjp(f, x, w, *weights)[1](g)
+        return grads
+
+    dx, dw, *dweights = jax.lax.cond(jnp.sum(sizes) <= rows, branch(rows),
+                                     branch(full), x, w, *weights)
+    return (dx, dw, None, None, None, *dweights)
+
+
+_bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
+
+
 def moe_dropless(p: Dict[str, jax.Array], x: jax.Array, m: MoEConfig,
                  valid: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The held experts' part of the layer, every assignment computed.
 
+    The products run over a static number of sorted rows.  A token chooses
+    k distinct experts, so at most ``R = T min(k, H)`` assignments land
+    here: the bound that makes the layer dropless at any routing.  A layer
+    expects ``T k H / E`` of them, so where ``HEADROOM`` times that (to
+    whole sublanes) is less than R, a layer whose assignments fit it runs
+    over those rows and any other over R: the same result, one branch
+    chosen at run time.  Where every expert is held there is one branch.
+
     Aux stats: ``lb_loss`` (the router's balance loss), ``load`` (E,) real
     tokens per expert over all E (the balancing bias's input), and the
     routing counters ``computed`` (assignments computed here),
-    ``max_held_load`` (the largest held expert's assignments) and
-    ``dropped`` (assignments to held experts whose row lies past the
-    products' rows: 0 at any routing, counted where the result is read)."""
+    ``max_held_load`` (the largest held expert's assignments),
+    ``overflow`` (1 where the layer took the R-row branch) and ``dropped``
+    (assignments to held experts whose row lies past the branch's rows: 0
+    at any routing, counted where the result is read)."""
     if m.router != "sigmoid":
         raise ValueError("the dropless layer balances with the sigmoid "
                          "router's bias and sequence-wise loss")
     B, S, D = x.shape
-    T, k, H = B * S, m.top_k, m.held
+    T, k, H, E = B * S, m.top_k, m.held, m.num_experts
     w, idx, probs = route(p, x, m)                               # (B, S, k)
     lb_loss, load = _seq_balance(idx, probs, valid, m)
     local = idx.reshape(T * k) - m.held_first
@@ -161,33 +236,25 @@ def moe_dropless(p: Dict[str, jax.Array], x: jax.Array, m: MoEConfig,
     key = jnp.where(here, local, H)                  # H: held elsewhere
     order = jnp.argsort(key, stable=True)            # held experts first
     sizes = jnp.bincount(key, length=H + 1)[:H].astype(jnp.int32)
-    # a token chooses k distinct experts, so at most min(k, H) are here:
-    # the static bound that makes the layer dropless at any routing
-    R = T * min(k, H)
     computed = jnp.sum(sizes)
-    live = (jnp.arange(R) < computed)[:, None]
-    # rows past the groups are never visited by the kernels: every product's
-    # output is masked there before it meets another product, forward and
-    # backward (a select, so that whatever those rows hold stays out)
-    gmm = lambda a, wt: jnp.where(live, kernel_ops.grouped_matmul(a, wt, sizes), 0)
-    xs = jnp.where(live, x.reshape(T, D)[order[:R] // k], 0)
-    ys = gmm(jax.nn.silu(gmm(xs, p["wi_gate"])) * gmm(xs, p["wi_up"]), p["wo"])
-    # back to the tokens: each (token, choice) held here reads its row of
-    # the sorted products (a gather, whose backward writes each row once),
-    # one choice at a time into a float32 sum
+    R = T * min(k, H)
+    fast = min(R, -(-HEADROOM * T * k * H // (8 * E)) * 8)
     slot = jnp.zeros((T * k,), jnp.int32).at[order].set(
         jnp.arange(T * k, dtype=jnp.int32))
-    dropped = jnp.sum(here & (slot >= R))           # held here, not in a row
-    slot = jnp.where(here, jnp.minimum(slot, R - 1), 0).reshape(T, k)
-    wk = jnp.where(here.reshape(T, k), w.reshape(T, k), 0.0)
-    out = jnp.zeros((T, D), jnp.float32)
-    for j in range(k):
-        out = out + wk[:, j:j + 1] * ys[slot[:, j]].astype(jnp.float32)
+    slot = jnp.where(here, slot, 0)
+    wk = jnp.where(here, w.reshape(T * k), 0.0).reshape(T, k)
+    args = (x.reshape(T, D), wk, order, slot.reshape(T, k), sizes,
+            p["wi_gate"], p["wi_up"], p["wo"])
+    out = (_bounded_experts(fast, R, *args) if fast < R
+           else _experts(R, *args))
+    overflow = (computed > fast).astype(jnp.int32)   # 0 where fast is R
+    rows = jnp.where(overflow > 0, R, fast)
+    dropped = jnp.sum(here & (slot >= rows))        # held here, not in a row
     out = out.astype(x.dtype).reshape(B, S, D)
     if m.num_shared_experts:
         out = out + _shared(p, x)
     stats = {"lb_loss": lb_loss, "load": load, "computed": computed,
-             "max_held_load": jnp.max(sizes),
+             "max_held_load": jnp.max(sizes), "overflow": overflow,
              "dropped": dropped,
              "drop_frac": jnp.zeros((), jnp.float32)}
     return out, stats

@@ -67,6 +67,7 @@ def _router_stats(router: Dict[str, Any]) -> Dict[str, Any]:
         out["moe_max_load"] = jnp.max(jnp.stack(
             [jnp.max(st["max_held_load"]) for st in flat]))
         out["moe_dropped"] = sum(jnp.sum(st["dropped"]) for st in flat)
+        out["moe_overflow"] = sum(jnp.sum(st["overflow"]) for st in flat)
     if flat:
         out["load_tree"] = {sec: [st.get("load") if st else None for st in lst]
                             for sec, lst in router.items()}

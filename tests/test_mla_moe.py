@@ -3,7 +3,8 @@ against the plain reference of the benchmark (``bench/reference/
 mla_moe.py``), at a smoke size on seeded random weights, float32 on the
 CPU: loss, per-leaf gradients and two optimizer steps with the balancing
 bias; the chip share's parts summing to the whole layer; no drops at a
-skewed routing; the flash kernel at qk 192 and v 128; the parameter trees
+skewed routing, on either branch of the bounded rows, and the bounded
+layer's gradients those of the full-size one; the flash kernel at qk 192 and v 128; the parameter trees
 of program and reference."""
 import importlib
 import json
@@ -124,6 +125,9 @@ def test_two_optimizer_steps_with_the_balancing_bias(setup):
     p, o = params, adamw_init(params)
     for b in batches:
         p, o, m = step(p, o, _program_batch(b))
+        # three held experts of eight at k = 3 compute ~1.1 T a layer at
+        # this near-uniform routing, under the fast branch's 2.25 T rows
+        assert int(m["moe_overflow"]) == 0
     losses, _, want = ref.Reference(cfg).train(params, batches)
     assert float(m["loss"]) == pytest.approx(losses[-1], rel=2e-5)
     for path_got, w in zip(jax.tree_util.tree_flatten_with_path(p)[0],
@@ -190,27 +194,100 @@ def test_chip_shares_add_up_to_the_uncut_layer(setup):
                                atol=2e-6, rtol=2e-5)
 
 
-def test_no_assignment_is_dropped_at_a_skewed_routing(setup):
-    """A bias that sends every token to expert 2 (held here): every
+SKEWS = {"fast": ((2,), 0), "full": ((2, 3, 4), 1)}
+
+
+def _skewed(cfg, biased):
+    """A layer holding experts 2-4 of 8 (k = 3, T = 128: rows R = 384, the
+    fast branch's 288), its inputs and the reference routing's top-k."""
+    bias = jnp.zeros((8,)).at[jnp.asarray(biased)].set(10.0)
+    p = _layer_params(cfg, 9, bias)
+    x = jax.random.normal(jax.random.key(10), (2, S, cfg["hidden_size"]))
+    idx = jax.lax.top_k(jax.nn.sigmoid(x @ p["router"]) + bias, 3)[1]
+    return _share(p, 2, 3), x, idx
+
+
+@pytest.mark.parametrize("skew", SKEWS)
+def test_no_assignment_is_dropped_at_a_skewed_routing(setup, skew):
+    """A bias that sends every token to expert 2 (held here), or to all
+    three held experts (every choice held: 3 T assignments, past the fast
+    branch's rows, so the layer takes the full-size branch): every
     assignment to the held experts is computed, the largest held load is
     every token, and the layer is the reference's."""
     cfg = setup[0]
-    bias = jnp.zeros((8,)).at[2].set(10.0)
-    p = _layer_params(cfg, 9, bias)
-    x = jax.random.normal(jax.random.key(10), (2, S, cfg["hidden_size"]))
+    biased, overflow = SKEWS[skew]
+    p, x, idx = _skewed(cfg, biased)
     before = MOE_PATHS.copy()
-    out, stats = moe_ffn(_share(p, 2, 3), x, _layer(cfg, 2, 3))
+    out, stats = moe_ffn(p, x, _layer(cfg, 2, 3))
     assert MOE_PATHS - before == {"dropless": 1}
-    idx = jax.lax.top_k(jax.nn.sigmoid(x @ p["router"]) + bias, 3)[1]
     held = int(jnp.sum((idx >= 2) & (idx < 5)))
+    loads = [int(jnp.sum(idx == e)) for e in (2, 3, 4)]
     assert int(stats["computed"]) == held
-    assert int(stats["max_held_load"]) == 2 * S
+    assert int(stats["max_held_load"]) == max(loads) == 2 * S
+    assert int(stats["overflow"]) == overflow
     assert int(stats["dropped"]) == 0
     real = jnp.ones((S,), jnp.float32)
-    want = jnp.stack([ref._experts(cfg, decoder._identity, x[b], _share(p, 2, 3),
-                                   real)[0] for b in range(2)])
+    want = jnp.stack([ref._experts(cfg, decoder._identity, x[b], p, real)[0]
+                      for b in range(2)])
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-6, rtol=2e-5)
+
+
+@pytest.mark.parametrize("skew", SKEWS)
+def test_bounded_layer_has_the_full_size_layers_gradients(setup, skew,
+                                                          monkeypatch):
+    """Per leaf, the gradients of the layer with its fast branch are those
+    of the layer forced to R rows (headroom past R: one branch), on the
+    branch each skew takes: both are exact, so only round-off differs."""
+    from repro.models import moe
+    cfg = setup[0]
+    p, x, _ = _skewed(cfg, SKEWS[skew][0])
+    layer = _layer(cfg, 2, 3)
+    cot = jax.random.normal(jax.random.key(11), x.shape)
+    loss = lambda p, x: jnp.sum(moe_ffn(p, x, layer)[0] * cot)
+    got = jax.grad(loss, argnums=(0, 1))(p, x)
+    monkeypatch.setattr(moe, "HEADROOM", 100)
+    want = jax.grad(loss, argnums=(0, 1))(p, x)
+    gaps = [_leaf_gap(g, w) for g, w in zip(jax.tree.leaves(got),
+                                             jax.tree.leaves(want))
+            if np.linalg.norm(np.asarray(w)) > 0]
+    assert len(gaps) == 8 and max(gaps) < 1e-5, gaps
+
+
+def _cond_outputs(jaxpr):
+    """The output shapes of every ``cond`` in ``jaxpr`` and the jaxprs it
+    holds, kernels' bodies left out (their ``pl.when``s are conds too)."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            continue
+        if e.primitive.name == "cond":
+            out.append([v.aval.shape for v in e.outvars])
+        for sub in jax.core.jaxprs_in_params(e.params):
+            out += _cond_outputs(sub)
+    return out
+
+
+def test_fast_path_carries_no_full_size_residuals(setup):
+    """The layer's forward and backward (``jax.vjp``) at the smoke size:
+    each picks its branch with a ``cond`` that outputs nothing of R rows
+    (no zero-filled residuals of the full-size branch on the fast path);
+    with every expert held there is one branch and no ``cond``."""
+    cfg = setup[0]
+    p = _layer_params(cfg, 9)
+    x = jax.random.normal(jax.random.key(10), (2, S, cfg["hidden_size"]))
+    cot = jnp.ones(x.shape)
+
+    def conds(params, layer):
+        f = lambda p, x: moe_ffn(p, x, layer)[0]
+        return _cond_outputs(jax.make_jaxpr(
+            lambda p, x: jax.vjp(f, p, x)[1](cot))(params, x).jaxpr)
+
+    R = 2 * S * 3
+    bounded = conds(_share(p, 2, 3), _layer(cfg, 2, 3))
+    assert len(bounded) == 2, bounded            # forward and backward
+    assert all(s[0] != R for shapes in bounded for s in shapes if s), bounded
+    assert conds(p, _layer(cfg, 0, 8)) == []
 
 
 class TestLatentAttentionKernel:
