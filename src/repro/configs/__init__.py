@@ -9,11 +9,12 @@ from __future__ import annotations
 from typing import List
 
 from ..models.config import ModelConfig
-from . import moonlight_16b_a3b, smollm_135m
+from . import kimi_linear_48b_a3b, moonlight_16b_a3b, smollm_135m
 
 _MODULES = {
     "smollm-135m": smollm_135m,
     "moonlight-16b-a3b": moonlight_16b_a3b,
+    "kimi-linear-48b-a3b": kimi_linear_48b_a3b,
 }
 
 ARCHS: List[str] = list(_MODULES)

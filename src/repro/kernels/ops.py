@@ -8,7 +8,8 @@ Where each kernel runs: the ingest plan's pack and erasure operators take
 ``pack_tokens`` and ``gf256_matmul`` when built with ``use_pallas``; the
 train step's self-attention takes ``flash_attention`` on a TPU wherever the
 kernel applies (``models.model._attention_path``); the dropless expert layer
-(``models.moe.moe_dropless``) takes ``grouped_matmul`` on every backend.
+(``models.moe.moe_dropless``) takes ``grouped_matmul`` on every backend;
+the KDA mixer (``models.kda``) takes ``kda`` on every backend.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ import jax
 from .flash_attention import flash_attention as _flash
 from .gf256_matmul import gf256_matmul as _gf256
 from .grouped_matmul import grouped_matmul as _gmm
+from .kda import kda as _kda
 from .pack_tokens import pack_tokens as _pack
 
 
@@ -59,6 +61,12 @@ def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
     return _gmm(lhs, rhs, group_sizes, interpret=interpret)
+
+
+def kda(q, k, v, log_a, beta, seg, *, scale: float, interpret: bool = None):
+    if interpret is None:
+        interpret = _default_interpret()
+    return _kda(q, k, v, log_a, beta, seg, scale=scale, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("seq_len", "pad_id", "interpret"))

@@ -147,6 +147,7 @@ def main() -> int:
     from ..core import DataStore
     from ..data.feeder import BlockFeeder, ingest_corpus
     from ..data.generators import gen_token_documents
+    from ..models.kda import KDA_PATHS
     from ..models.model import ATTENTION_PATHS, ATTENTION_RESIDUALS
     from ..models.params import abstract_params
     from ..training.checkpoint import CheckpointManager
@@ -198,6 +199,7 @@ def main() -> int:
     losses = []
     traced = Counter(ATTENTION_PATHS)
     traced_residuals = Counter(ATTENTION_RESIDUALS)
+    traced_kda = Counter(KDA_PATHS)
     for i, raw in enumerate(feeder.batches(args.steps)):
         batch = trainer.put_batch(make_batch(raw, args.seq_len))
         params, opt_state, metrics = trainer.step(params, opt_state, batch)
@@ -205,7 +207,8 @@ def main() -> int:
         if i == 0:   # the first call traced the step
             print(f"[train] attention calls traced by path: "
                   f"{dict(ATTENTION_PATHS - traced)}, kernel residuals: "
-                  f"{dict(ATTENTION_RESIDUALS - traced_residuals)}")
+                  f"{dict(ATTENTION_RESIDUALS - traced_residuals)}, KDA "
+                  f"calls: {dict(KDA_PATHS - traced_kda)}")
         losses.append(float(metrics["loss"]))
         if step % args.log_every == 0:
             dt = (time.time() - t0) / (i + 1)

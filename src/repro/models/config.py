@@ -1,10 +1,11 @@
 """Model configuration of the decoder the benchmark trains.
 
 One definition covers the registered architectures: unrolled leading
-layers, then a scanned pattern of layers (the parameter tree also keeps a
-``remainder`` list after the scan, empty for every registered config).
-Every layer is causal self-attention, grouped-query or latent (``mla``),
-followed by a SwiGLU or mixture-of-experts feed-forward.
+layers, then a scanned pattern of layers, then unrolled ``remainder``
+layers.  A layer's mixer is causal self-attention, grouped-query or latent
+(``mla``), or Kimi Delta Attention (``kda``, a delta-rule recurrence whose
+state resets at every packed document), followed by a SwiGLU or
+mixture-of-experts feed-forward.
 """
 from __future__ import annotations
 
@@ -14,9 +15,9 @@ from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
-#: the one block kind: causal self-attention (grouped-query, or latent where
-#: ``mla`` is set), then the MLP
-BLOCK_KINDS = ("attn",)
+#: block kinds, by mixer: causal self-attention (grouped-query, or latent
+#: where ``mla`` is set), or Kimi Delta Attention (``kda``); then the MLP
+BLOCK_KINDS = ("attn", "kda")
 #: MLP kinds: a dense SwiGLU of width d_ff, or the mixture of experts
 MLP_KINDS = ("swiglu", "moe")
 #: the MLP kind of the leading (dense) layers
@@ -68,10 +69,26 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # rotate the qk_rope_head_dim part of q and k (False: Kimi-Linear's
+    # ``mla_use_nope``, the part is kept and shared but not rotated)
+    rope: bool = True
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention (the Kimi Linear report, arXiv:2510.26692):
+    per head a (head_dim, head_dim) state updated by the delta rule with a
+    decay per key channel, q, k and v through a causal depthwise short
+    convolution and SiLU, the decay and the output gate through rank
+    ``gate_rank`` projections."""
+    n_heads: int = 32
+    head_dim: int = 128           # d_k = d_v
+    conv_size: int = 4
+    gate_rank: int = 128
 
 
 @dataclass(frozen=True)
@@ -99,6 +116,7 @@ class ModelConfig:
 
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
+    kda: Optional[KDAConfig] = None
 
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
@@ -125,6 +143,8 @@ class ModelConfig:
             if kind not in BLOCK_KINDS:
                 raise ValueError(f"{self.name}: unknown block kind {kind!r}; "
                                  f"have {BLOCK_KINDS}")
+            if kind == "kda" and self.kda is None:
+                raise ValueError(f"{self.name}: a kda block needs ``kda``")
         if self.mlp_kind not in MLP_KINDS:
             raise ValueError(f"{self.name}: unknown mlp kind "
                              f"{self.mlp_kind!r}; have {MLP_KINDS}")
@@ -151,6 +171,11 @@ class ModelConfig:
         rest = len(self.pattern) * self.pattern_repeats + len(self.remainder)
         return (LEADING_MLP,) * len(self.leading) + (self.mlp_kind,) * rest
 
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The block kind of every layer, in stack order."""
+        return (self.leading + self.pattern * self.pattern_repeats
+                + self.remainder)
+
     @property
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
@@ -165,8 +190,9 @@ class ModelConfig:
         total = V * D  # embedding
         if not self.tied_embeddings:
             total += V * D
-        for mlp_kind in self.layer_mlps():
-            total += self._attn_params() + self._mlp_params(mlp_kind) + 2 * D
+        for kind, mlp_kind in zip(self.layer_kinds(), self.layer_mlps()):
+            mixer = self._kda_params() if kind == "kda" else self._attn_params()
+            total += mixer + self._mlp_params(mlp_kind) + 2 * D
         total += D  # final norm
         return total
 
@@ -191,6 +217,14 @@ class ModelConfig:
         kv = (D * (a.kv_lora_rank + a.qk_rope_head_dim) + a.kv_lora_rank
               + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim))
         return q + kv + H * a.v_head_dim * D
+
+    def _kda_params(self) -> int:
+        a, D = self.kda, self.d_model
+        HK = a.n_heads * a.head_dim
+        proj = 3 * D * HK + HK * D                     # q, k, v, o
+        conv = 3 * a.conv_size * HK
+        gates = 2 * (D * a.gate_rank + a.gate_rank * HK) + D * a.n_heads
+        return proj + conv + gates + a.n_heads + 2 * HK + a.head_dim
 
     def _mlp_params(self, mlp_kind: str) -> int:
         if mlp_kind == "swiglu":

@@ -5,9 +5,11 @@ cfg.remainder):
 
   tokens ──embed──▶ [leading] ─▶ [ scan over repeats: pattern blocks ] ─▶ [remainder] ─▶ norm ─▶ unembed
 
-Every block is causal self-attention over the row's segments
-(grouped-query, or multi-head latent attention where ``cfg.mla`` is set),
-pre-norm residual: x + attn(norm(x)), then x + mlp(norm(x)) where the MLP
+Every block mixes the row's segments, each on its own: causal
+self-attention (grouped-query, or multi-head latent attention where
+``cfg.mla`` is set) or, in a ``kda`` block, Kimi Delta Attention
+(``models/kda.py``); pre-norm residual: x + mixer(norm(x)), then
+x + mlp(norm(x)) where the MLP
 is the dense ``config.LEADING_MLP`` for the leading layers and
 ``cfg.mlp_kind`` (SwiGLU or MoE) for the rest.  ``forward`` returns the
 hidden states; the train step (``training/steps.py``) takes the chunked
@@ -26,6 +28,8 @@ from ..kernels.flash_attention import RESIDUALS as FLASH_RESIDUALS
 from ..kernels.flash_attention import supports as flash_supports
 from . import attention as attn
 from .config import LEADING_MLP, ModelConfig
+from .kda import OUTPUT as KDA_OUTPUT
+from .kda import kda_defs, kda_mixer
 from .layers import embed, embedding_defs, mlp, mlp_defs, rmsnorm, rmsnorm_defs
 from .moe import moe_defs, moe_ffn
 from .params import ParamDef, stack_tree
@@ -68,10 +72,11 @@ def attn_defs(cfg: ModelConfig) -> Dict[str, Any]:
     }
 
 
-def block_defs(cfg: ModelConfig, mlp_kind: Optional[str] = None) -> Dict[str, Any]:
+def block_defs(cfg: ModelConfig, mlp_kind: Optional[str] = None,
+               kind: str = "attn") -> Dict[str, Any]:
     mlp_kind = mlp_kind or cfg.mlp_kind
     return {"pre_norm": rmsnorm_defs(cfg.d_model),
-            "attn": attn_defs(cfg),
+            "attn": kda_defs(cfg) if kind == "kda" else attn_defs(cfg),
             "mlp_norm": rmsnorm_defs(cfg.d_model),
             "mlp": moe_defs(cfg) if mlp_kind == "moe" else mlp_defs(cfg)}
 
@@ -81,10 +86,10 @@ def model_defs(cfg: ModelConfig) -> Dict[str, Any]:
     config with leading layers has a ``leading`` list of them."""
     defs: Dict[str, Any] = {"embed": embedding_defs(cfg)}
     if cfg.leading:
-        defs["leading"] = [block_defs(cfg, LEADING_MLP) for _ in cfg.leading]
-    defs["pattern"] = ([stack_tree(block_defs(cfg), cfg.pattern_repeats)
-                        for _ in cfg.pattern] if cfg.pattern_repeats > 0 else [])
-    defs["remainder"] = [block_defs(cfg) for _ in cfg.remainder]
+        defs["leading"] = [block_defs(cfg, LEADING_MLP, kind) for kind in cfg.leading]
+    defs["pattern"] = ([stack_tree(block_defs(cfg, kind=kind), cfg.pattern_repeats)
+                        for kind in cfg.pattern] if cfg.pattern_repeats > 0 else [])
+    defs["remainder"] = [block_defs(cfg, kind=kind) for kind in cfg.remainder]
     defs["final_norm"] = rmsnorm_defs(cfg.d_model)
     return defs
 
@@ -128,7 +133,8 @@ def _mla_qkv(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     """Latent attention's projections (the equations of
     ``configs/moonlight_16b_a3b.py``): q (B, S, H, nope + rope) in float32,
     unrounded; k (B, S, H, nope + rope) and v (B, S, H, v_head) in the
-    activation dtype, the rotary part of k shared by every head."""
+    activation dtype, the rotary part of k shared by every head (rotated
+    unless ``mla.rope`` is off)."""
     a = cfg.mla
     dt = x.dtype
     if a.q_lora_rank:
@@ -138,14 +144,16 @@ def _mla_qkv(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["wq"],
                        preferred_element_type=jnp.float32)
+    rope = ((lambda t: attn.rope(t, pos, cfg.rope_theta)) if a.rope
+            else (lambda t: t))
     q_nope, q_pe = jnp.split(q, [a.qk_nope_head_dim], axis=-1)
-    q = jnp.concatenate([q_nope, attn.rope(q_pe, pos, cfg.rope_theta)], axis=-1)
+    q = jnp.concatenate([q_nope, rope(q_pe)], axis=-1)
     ckv = x @ p["wkv_a"]
     c, k_pe = jnp.split(ckv, [a.kv_lora_rank], axis=-1)
     c = rmsnorm(p["kv_norm"], c, cfg.norm_eps)
     kv = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"])
     k_nope, v = jnp.split(kv, [a.qk_nope_head_dim], axis=-1)
-    k_pe = attn.rope(k_pe[:, :, None, :], pos, cfg.rope_theta)
+    k_pe = rope(k_pe[:, :, None, :])
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe, k_nope.shape[:3] + k_pe.shape[3:])],
         axis=-1).astype(dt)
@@ -211,25 +219,33 @@ def _apply_mlp(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
 
 def apply_block(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
                 *, seg: jax.Array, pos: jax.Array,
-                constrain=None, mlp_kind: Optional[str] = None
+                constrain=None, mlp_kind: Optional[str] = None,
+                kind: str = "attn"
                 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
-    """One layer.  Returns (x, moe_aux_loss, router stats)."""
+    """One layer.  Returns (x, moe_aux_loss, stats: the router's, and a
+    KDA mixer's ``kda_resets``)."""
     h = rmsnorm(p["pre_norm"], x, cfg.norm_eps)
-    x = x + _self_attention(cfg, p["attn"], h, seg, pos)
+    mixed = {}
+    if kind == "kda":
+        out, mixed = kda_mixer(cfg, p["attn"], h, seg)
+        x = x + out
+    else:
+        x = x + _self_attention(cfg, p["attn"], h, seg, pos)
     h = rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
     out, aux, stats = _apply_mlp(cfg, p["mlp"], h, constrain=constrain,
                                  mlp_kind=mlp_kind, valid=seg > 0)
-    return x + out, aux, stats
+    return x + out, aux, {**mixed, **stats}
 
 
 def _remat_policy(cfg: ModelConfig):
     """The layer checkpoint's policy.  Every policy but ``nothing`` keeps
-    the attention kernel's residuals (``FLASH_RESIDUALS``); a call on a jnp
-    path carries no such name and is recomputed as the policy says."""
+    the attention kernel's residuals (``FLASH_RESIDUALS``) and a KDA
+    mixer's output (``KDA_OUTPUT``); a call on a jnp path carries no such
+    name and is recomputed as the policy says."""
     cp = jax.checkpoint_policies
     if cfg.remat_policy == "nothing":
         return cp.nothing_saveable
-    residuals = cp.save_only_these_names(FLASH_RESIDUALS)
+    residuals = cp.save_only_these_names(FLASH_RESIDUALS, KDA_OUTPUT)
     if cfg.remat_policy == "save_layer_inputs":
         return residuals
     return cp.save_from_both_policies({
@@ -257,15 +273,17 @@ def forward(cfg: ModelConfig, params: Dict[str, Any], batch: Dict[str, jax.Array
     x = pin(embed(params["embed"], batch["tokens"], cfg))
     router: Dict[str, Any] = {}
 
-    def unrolled(section, mlp_kind, x, aux):
+    def unrolled(section, kinds, mlp_kind, x, aux):
         # per-layer remat for unrolled blocks (the scan body's policy)
-        blk = jax.checkpoint(
-            lambda p, h: apply_block(cfg, p, h, seg=seg, pos=pos,
-                                     constrain=constrain, mlp_kind=mlp_kind),
-            policy=_remat_policy(cfg), prevent_cse=False)
+        def blk(kind):
+            return jax.checkpoint(
+                lambda p, h: apply_block(cfg, p, h, seg=seg, pos=pos,
+                                         constrain=constrain, mlp_kind=mlp_kind,
+                                         kind=kind),
+                policy=_remat_policy(cfg), prevent_cse=False)
         stats = []
-        for layer_params in params[section]:
-            x, a, st = blk(layer_params, x)
+        for kind, layer_params in zip(kinds, params[section]):
+            x, a, st = blk(kind)(layer_params, x)
             x = pin(x)
             aux = aux + a
             stats.append(st)
@@ -277,7 +295,8 @@ def forward(cfg: ModelConfig, params: Dict[str, Any], batch: Dict[str, jax.Array
         stats = []
         for i in range(len(cfg.pattern)):
             h, a, st = apply_block(cfg, layer_params[i], h,
-                                   seg=seg, pos=pos, constrain=constrain)
+                                   seg=seg, pos=pos, constrain=constrain,
+                                   kind=cfg.pattern[i])
             h = pin(h)
             aux = aux + a
             stats.append(st)
@@ -285,13 +304,13 @@ def forward(cfg: ModelConfig, params: Dict[str, Any], batch: Dict[str, jax.Array
 
     aux = jnp.zeros((), jnp.float32)
     if cfg.leading:
-        x, aux = unrolled("leading", LEADING_MLP, x, aux)
+        x, aux = unrolled("leading", cfg.leading, LEADING_MLP, x, aux)
     router["pattern"] = []
     if cfg.pattern_repeats > 0:
         body_r = jax.checkpoint(body, policy=_remat_policy(cfg),
                                 prevent_cse=False)
         (x, aux), router["pattern"] = jax.lax.scan(body_r, (x, aux),
                                                    params["pattern"])
-    x, aux = unrolled("remainder", cfg.mlp_kind, x, aux)
+    x, aux = unrolled("remainder", cfg.remainder, cfg.mlp_kind, x, aux)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return x, {"moe_aux": aux, "router": router}

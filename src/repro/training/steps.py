@@ -58,16 +58,21 @@ def _chunked_xent(cfg: ModelConfig, params: Dict[str, Any], hidden: jax.Array,
 
 def _router_stats(router: Dict[str, Any]) -> Dict[str, Any]:
     """Per-step routing counters over the MoE layers that report them
-    (dropless dispatch), and each MoE layer's expert loads in the params'
-    layout (``load_tree``: None at layers without a router)."""
+    (dropless dispatch), each MoE layer's expert loads in the params'
+    layout (``load_tree``: None at layers without a router), and the state
+    resets of the KDA layers (``kda_resets``)."""
     flat = [st for section in router.values() for st in section if st]
     out: Dict[str, Any] = {}
-    if any("computed" in st for st in flat):
-        out["moe_computed"] = sum(jnp.sum(st["computed"]) for st in flat)
+    kda = [st["kda_resets"] for st in flat if "kda_resets" in st]
+    if kda:
+        out["kda_resets"] = sum(jnp.sum(r) for r in kda)
+    moe = [st for st in flat if "computed" in st]
+    if moe:
+        out["moe_computed"] = sum(jnp.sum(st["computed"]) for st in moe)
         out["moe_max_load"] = jnp.max(jnp.stack(
-            [jnp.max(st["max_held_load"]) for st in flat]))
-        out["moe_dropped"] = sum(jnp.sum(st["dropped"]) for st in flat)
-        out["moe_overflow"] = sum(jnp.sum(st["overflow"]) for st in flat)
+            [jnp.max(st["max_held_load"]) for st in moe]))
+        out["moe_dropped"] = sum(jnp.sum(st["dropped"]) for st in moe)
+        out["moe_overflow"] = sum(jnp.sum(st["overflow"]) for st in moe)
     if flat:
         out["load_tree"] = {sec: [st.get("load") if st else None for st in lst]
                             for sec, lst in router.items()}

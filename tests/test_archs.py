@@ -54,8 +54,8 @@ class TestArchSmoke:
     ("mlp_kind", "geglu"), ("mlp_kind", "gelu"), ("mlp_kind", "none"),
 ])
 def test_unknown_block_and_mlp_kinds_are_refused(field, kind):
-    """The decoder has one block kind and two MLP kinds: any other is an
-    error when the config is made, not deep inside tracing."""
+    """The decoder has two block kinds (attn, kda) and two MLP kinds: any
+    other is an error when the config is made, not deep inside tracing."""
     value = (kind,) if field == "pattern" else kind
     with pytest.raises(ValueError, match=f"unknown .* kind {kind!r}"):
         ModelConfig(**{field: value})
@@ -75,3 +75,20 @@ def test_moe_active_params():
     moonlight = get_config("moonlight-16b-a3b")
     assert 15.9e9 < moonlight.param_count() < 16.0e9
     assert 2.9e9 < moonlight.active_param_count() < 3.0e9
+
+
+def test_kimi_linear_sizes():
+    """Kimi-Linear-48B-A3B: 48 B parameters (49.1 B by this count: the
+    published name rounds), 3 B of them active per token (8 of 256 routed
+    experts and the shared one); KDA and latent attention 3 : 1 as
+    ``linear_attn_config`` lists them."""
+    kimi = get_config("kimi-linear-48b-a3b")
+    assert 48e9 < kimi.param_count() < 50e9
+    assert 3.0e9 < kimi.active_param_count() < 3.6e9
+    full = [i + 1 for i, k in enumerate(kimi.layer_kinds()) if k == "attn"]
+    assert full == [4, 8, 12, 16, 20, 24, 27]
+
+
+def test_kda_block_needs_its_config():
+    with pytest.raises(ValueError, match="kda block needs"):
+        ModelConfig(pattern=("kda",))

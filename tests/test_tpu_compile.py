@@ -79,3 +79,21 @@ def test_flash_attention_compiles_at_the_fed_cell_shape(shape, pass_):
         fn = jax.grad(lambda q, k, v, s: flash_attention(q, k, v, s).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))
     assert "tpu_custom_call" in compiled_text(fn, q, kv, kv, seg)
+
+
+@pytest.mark.parametrize("pass_", ["forward", "grad"])
+def test_kda_kernels_compile_at_the_kimi_cell_shape(shape, pass_):
+    """The chunked KDA at the Kimi-Linear cell's widths: heads of an
+    8192-token row, d_k = d_v = 128; the kernels ``kda_fwd`` (and
+    ``kda_bwd`` in the gradient) are in the program."""
+    from repro.kernels.kda import kda
+    qkv = shape((1, 4, 8192, 128), jnp.bfloat16)
+    args = (qkv, qkv, qkv, shape((1, 4, 8192, 128), jnp.float32),
+            shape((1, 4, 8192), jnp.float32), shape((1, 8192), jnp.int32))
+    fn = lambda *a: kda(*a, scale=128 ** -0.5)[0]
+    if pass_ == "grad":
+        fn = jax.grad(lambda *a: kda(*a, scale=128 ** -0.5)[0].sum(),
+                      argnums=(0, 1, 2, 3, 4))
+    text = compiled_text(fn, *args)
+    assert "tpu_custom_call" in text and "kda_fwd" in text
+    assert pass_ == "forward" or "kda_bwd" in text
